@@ -43,12 +43,17 @@ Prints ``name,us_per_call,derived`` CSV. Mapping to the paper:
 The CI regression gate over the tiny-size variants of kernel_bench,
 serve_bench, adapt_bench, fleet_bench and cluster_bench lives in
 ``benchmarks/bench_smoke.py``.
+
+A suite that raises prints a ``SUITE-ERROR`` row and the others still
+run; the harness then exits non-zero.  Compiled programs persist in
+the compile cache (``repro.compile_cache``).
 """
 
 from __future__ import annotations
 
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -60,7 +65,9 @@ def main() -> None:
     )
 
     from benchmarks.bench_smoke import SMOKE_KWARGS
+    from repro.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     suites = [
         # --quick reuses the bench-smoke gate's tiny settings so CI and
@@ -97,17 +104,22 @@ def main() -> None:
          if quick else {}),
     ]
     print("name,us_per_call,derived")
+    failed = []
     for name, fn, kwargs in suites:
         t0 = time.time()
         try:
             rows = fn(**kwargs)
         except Exception as e:  # a failing suite must not hide others
+            traceback.print_exc()
             print(f"{name}/SUITE-ERROR,0,{e!r}")
+            failed.append(name)
             continue
         for rname, us, derived in rows:
             print(f"{rname},{us:.2f},{derived}")
         print(f"# suite {name} took {time.time()-t0:.1f}s",
               file=sys.stderr)
+    if failed:
+        sys.exit(f"suites failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -14,6 +14,7 @@ import dataclasses
 import jax
 
 from repro import configs as C
+from repro.compile_cache import enable_compile_cache
 from repro.core.cost_model import HOST_LATENCY, HOST_LINK_BW
 from repro.core.hep_shard import ShardTrial, search
 from repro.launch import hlo_analysis as H
@@ -21,6 +22,7 @@ from repro.launch.dryrun import HBM_BW, ICI_BW, PEAK_BF16, build_lowered
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo_1b")
     ap.add_argument("--shape", default="train_4k")

@@ -19,6 +19,7 @@ from repro.bnn.models import (
     forward_packed, pack_params, prepare_input_packed,
 )
 from repro.bnn.train import eval_step, init_train_state, train_step
+from repro.compile_cache import enable_compile_cache
 from repro.core import build_mapped_model
 from repro.core.mapper import best_uniform
 from repro.data import ShardedBatcher, make_image_dataset
@@ -26,6 +27,7 @@ from repro.serving import ServingEngine
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="shrink model/steps/profiling for CI")

@@ -34,6 +34,7 @@ from repro.bnn import build_model
 from repro.bnn.models import (
     forward_packed, pack_params, prepare_input_packed,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.core.parallel_config import CPU, FULL_GPU
 from repro.store import ProfileStore, fleet_scope
 
@@ -41,6 +42,7 @@ SPACE = (CPU, FULL_GPU)
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.5)
     ap.add_argument("--requests", type=int, default=128,

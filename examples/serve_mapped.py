@@ -25,10 +25,12 @@ from repro.bnn import build_model
 from repro.bnn.models import (
     forward_packed, pack_params, prepare_input_packed,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.data import make_image_dataset
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.5)
     ap.add_argument("--requests", type=int, default=512)

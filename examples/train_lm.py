@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs as C
+from repro.compile_cache import enable_compile_cache
 from repro.data import make_token_stream
 from repro.models.steps import make_train_step
 from repro.models.transformer import init_params
@@ -25,6 +26,7 @@ from repro.runtime import LoopConfig, TrainLoop
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2_0_5b")
     ap.add_argument("--steps", type=int, default=60)

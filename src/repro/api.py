@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Sequence
 
 from repro.core.mapper import (
@@ -100,7 +101,8 @@ class TenantPlan:
     nested-width subnet levels; its level 0 is this plan.
     ``quality_floor`` is the deepest subnet level the tenant may be
     degraded to (``None`` = the narrowest planned level; 0 pins full
-    width)."""
+    width).  ``phase_s`` holds :func:`plan_single`'s wall seconds per
+    planning phase (``profile``, ``map``, and ``fuse`` when fused)."""
 
     name: str
     model: object
@@ -112,6 +114,7 @@ class TenantPlan:
     deadline_s: float = math.inf
     elastic: object = None
     quality_floor: int | None = None
+    phase_s: dict = dataclasses.field(default_factory=dict)
 
     @property
     def expected_s_per_example(self) -> float:
@@ -183,18 +186,23 @@ def plan_single(
         autotune=autotune, configs=configs, repeats=repeats,
         time_source=time_source, registry=registry,
     )
+    t0 = time.perf_counter()
     if store is not None:
         table, _ = store.get_or_profile(
             model, packed, profile, batch_sizes=batch_sizes
         )
     else:
         table = profile(model, packed, batch_sizes=batch_sizes)
+    t1 = time.perf_counter()
     config = map_model(table, policy=policy, configs=configs)
+    t2 = time.perf_counter()
+    phase_s = {"profile": t1 - t0, "map": t2 - t1}
     if fuse:
         config = fuse_mapping(
             model, packed, table, config,
             registry=registry, time_source=time_source, repeats=repeats,
         )
+        phase_s["fuse"] = time.perf_counter() - t2
         if store is not None:
             store.save_profile(table)   # now carries the segment rows
     if store is not None:
@@ -202,6 +210,7 @@ def plan_single(
     return TenantPlan(
         name=name or getattr(model, "name", table.model_name),
         model=model, packed=packed, table=table, config=config,
+        phase_s=phase_s,
     )
 
 

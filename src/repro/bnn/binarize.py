@@ -64,11 +64,11 @@ def pack_bits(x: jax.Array, pad_bit: int = 0) -> jax.Array:
         fill = jnp.full(bits.shape[:-1] + (pad,), bool(pad_bit))
         bits = jnp.concatenate([bits, fill], axis=-1)
     bits = bits.reshape(bits.shape[:-1] + (n_words, PACK_W))
-    shifts = jnp.arange(PACK_W, dtype=jnp.uint32)
-    words = jnp.sum(
-        bits.astype(jnp.uint32) << shifts, axis=-1, dtype=jnp.uint32
-    )
-    return words.astype(jnp.int32)
+    # int32 throughout (Mosaic has no reductions over unsigned integers):
+    # the 32 lane values are disjoint bits, so their wrapping sum is the
+    # packed word, with lane 31 landing in the sign bit
+    shifts = jnp.arange(PACK_W, dtype=jnp.int32)
+    return jnp.sum(bits.astype(jnp.int32) << shifts, axis=-1, dtype=jnp.int32)
 
 
 def unpack_bits(words: jax.Array, n: int) -> jax.Array:
@@ -83,8 +83,9 @@ def unpack_bits(words: jax.Array, n: int) -> jax.Array:
 
 
 def popcount(x: jax.Array) -> jax.Array:
-    """Population count on int32 words, result int32."""
-    return jax.lax.population_count(x.astype(jnp.uint32)).astype(jnp.int32)
+    """Population count on int32 words, result int32 (two's-complement
+    bits, so the sign bit counts like any other lane)."""
+    return jax.lax.population_count(x.astype(jnp.int32))
 
 
 def xnor_dot_words(a_words: jax.Array, w_words: jax.Array, k_true: int) -> jax.Array:

@@ -10,9 +10,9 @@
 
 Each kernel ships with ``ref.py`` (pure-jnp oracle) and ``ops.py``
 (jit'd entry points). ``variants.py`` holds the pure-XLA aspect-
-structured implementations that the live profiler times on the host
-platform (kernels are validated in interpret mode; their TPU cost comes
-from the analytic model in ``repro.core.cost_model``).
+structured implementations that the live profiler times on whatever
+platform it runs on.  Pallas kernels run natively on a TPU and in
+interpret mode elsewhere; ``chip_smoke.py`` checks them on the chip.
 
 ``registry.py`` is the open kernel-variant registry: every GEMM
 implementation — the fixed 8, the fused device reference, Pallas tile

@@ -18,8 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.pallas_compat import compiler_params_kwargs, vmem_scratch
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
@@ -94,13 +93,10 @@ def flash_attention_pallas(
     nq, nk = Sq // q_blk, Sk // k_blk
 
     scratch = [
-        vmem_scratch((q_blk, 1), jnp.float32),
-        vmem_scratch((q_blk, 1), jnp.float32),
-        vmem_scratch((q_blk, D), jnp.float32),
+        pltpu.VMEM((q_blk, 1), jnp.float32),
+        pltpu.VMEM((q_blk, 1), jnp.float32),
+        pltpu.VMEM((q_blk, D), jnp.float32),
     ]
-    extra = compiler_params_kwargs(
-        ("parallel", "parallel", "parallel", "arbitrary")
-    )
 
     return pl.pallas_call(
         functools.partial(
@@ -126,5 +122,9 @@ def flash_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-        **extra,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary"
+            )
+        ),
     )(q, k, v)

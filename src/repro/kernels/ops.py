@@ -3,7 +3,8 @@
 ``backend`` selects the execution tier:
   * ``'ref'``     — pure-jnp oracle (the CPU implementation)
   * ``'variant'`` — aspect-structured XLA program (profiled live)
-  * ``'pallas'``  — the Pallas TPU kernel (``interpret=True`` on CPU)
+  * ``'pallas'``  — the Pallas TPU kernel, native on a TPU backend and
+    in interpret mode elsewhere (``interpret=None`` picks by backend)
 """
 
 from __future__ import annotations
@@ -18,6 +19,10 @@ from repro.kernels.xnor_popcount import xnor_gemm_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 
 
+def _interpret(interpret: bool | None) -> bool:
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
 @partial(
     jax.jit,
     static_argnames=("k_true", "aspects", "backend", "interpret",
@@ -30,7 +35,7 @@ def xnor_gemm(
     k_true: int,
     aspects: tuple = ("X", "Y", "Z"),
     backend: str = "ref",
-    interpret: bool = True,
+    interpret: bool | None = None,
     p_blk: int = 128,
     n_blk: int = 128,
 ) -> jax.Array:
@@ -41,7 +46,7 @@ def xnor_gemm(
     if backend == "pallas":
         return xnor_gemm_pallas(
             a, w, k_true, aspects,
-            p_blk=p_blk, n_blk=n_blk, interpret=interpret,
+            p_blk=p_blk, n_blk=n_blk, interpret=_interpret(interpret),
         )
     raise ValueError(f"unknown backend {backend!r}")
 
@@ -58,7 +63,7 @@ def binary_conv2d(
     k_true: int,
     aspects: tuple = ("X", "Y", "Z"),
     backend: str = "ref",
-    interpret: bool = True,
+    interpret: bool | None = None,
     p_blk: int = 128,
     n_blk: int = 128,
 ) -> jax.Array:
@@ -89,7 +94,7 @@ def flash_attention(
     causal: bool = True,
     scale: float | None = None,
     backend: str = "pallas",
-    interpret: bool = True,
+    interpret: bool | None = None,
     q_blk: int = 128,
     k_blk: int = 128,
 ) -> jax.Array:
@@ -99,5 +104,5 @@ def flash_attention(
         )
     return flash_attention_pallas(
         q, k, v, causal=causal, scale=scale,
-        q_blk=q_blk, k_blk=k_blk, interpret=interpret,
+        q_blk=q_blk, k_blk=k_blk, interpret=_interpret(interpret),
     )

@@ -279,9 +279,20 @@ def _pallas_builder(p_blk: int, n_blk: int) -> Callable:
     return build
 
 
-def _pallas_applicable(shape: GemmShape, platform: str) -> bool:
-    # native on TPU; interpret mode elsewhere only for small problems
-    return platform == "tpu" or shape.work <= PALLAS_INTERPRET_MAX_WORK
+def _pallas_applicable(p_blk: int, n_blk: int) -> Callable:
+    def applicable(shape: GemmShape, platform: str) -> bool:
+        if platform != "tpu":
+            # interpret mode elsewhere, only for small problems
+            return shape.work <= PALLAS_INTERPRET_MAX_WORK
+        # Mosaic's block rule for the (1, p, n) output block: the lane
+        # block is a multiple of 128 or the whole N, the sublane block a
+        # multiple of 8 or the whole P (the kernel clips tiles to P, N)
+        p, n = min(p_blk, shape.p), min(n_blk, shape.n)
+        return (n % 128 == 0 or n == shape.n) and (
+            p % 8 == 0 or p == shape.p
+        )
+
+    return applicable
 
 
 # the fused kernel keeps every weight + the widest intermediate
@@ -298,9 +309,15 @@ def _seg_pallas_builder(specs, packed_params, in_encoding=None):
 
 
 def _seg_pallas_applicable(shape: SegmentShape, platform: str) -> bool:
+    if platform == "tpu":
+        # Mosaic refuses the fused body's one-example blocks (a (1, N)
+        # output block is neither 8-row aligned nor the whole batch);
+        # the TPU keeps seg_xla until a Mosaic-shaped fused kernel
+        # exists (ROADMAP A6)
+        return False
     if shape.vmem_bytes > SEGMENT_VMEM_BUDGET:
         return False
-    return platform == "tpu" or shape.work <= PALLAS_INTERPRET_MAX_WORK
+    return shape.work <= PALLAS_INTERPRET_MAX_WORK
 
 
 def _seg_xla_applicable(shape: SegmentShape, platform: str) -> bool:
@@ -354,7 +371,7 @@ def _register_defaults(reg: VariantRegistry) -> VariantRegistry:
                 p_blk=p_blk,
                 n_blk=n_blk,
                 analytic="tiled",
-                applicable=_pallas_applicable,
+                applicable=_pallas_applicable(p_blk, n_blk),
                 description=f"Pallas xnor_popcount kernel, "
                 f"{p_blk}x{n_blk} window/neuron tiles",
             )

@@ -24,7 +24,9 @@ price and select them like any other variant:
   threshold + bitplane repack, flatten, FC — unrolled inside the
   kernel body.  Interior activations live only in VMEM/registers;
   HBM sees packed words at the segment edges (plus the final int32
-  scores).  Runs natively on TPU and in interpret mode elsewhere.
+  scores).  Runs in interpret mode off the TPU.  Mosaic refuses its
+  one-example blocks, so the registry does not offer it on the TPU
+  (``kernels/registry.py``).
 
 Both builders compute the exact reference semantics (they reuse the
 ``repro.bnn.layers`` packed ops on a per-example block), so fused
@@ -46,10 +48,10 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.bnn import layers as L
 from repro.bnn.binarize import PACK_W
-from repro.kernels.pallas_compat import compiler_params_kwargs
 
 PACKED = "packed"
 UNPACKED = "unpacked"
@@ -285,7 +287,9 @@ def build_pallas_segment(
             ),
             out_shape=jax.ShapeDtypeStruct((b,) + out_shape, jnp.int32),
             interpret=interpret,
-            **compiler_params_kwargs(("parallel",)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)
+            ),
         )
         return call(x, *arrays)
 

@@ -33,8 +33,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.pallas_compat import compiler_params_kwargs
+from jax.experimental.pallas import tpu as pltpu
 
 ASPECTS_ALL = ("X", "Y", "Z")
 
@@ -108,5 +107,5 @@ def xnor_gemm_pallas(
         out_specs=pl.BlockSpec((1, p_blk, n_blk), o_index),
         out_shape=jax.ShapeDtypeStruct((B, P, N), jnp.int32),
         interpret=interpret,
-        **compiler_params_kwargs(dim_sem),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=dim_sem),
     )(a, w)

@@ -488,7 +488,10 @@ def test_controller_serves_bit_exact_across_live_remap(small):
     """End to end through the controller: drift injected between
     steps, outputs stay bit-exact with the reference throughout."""
     m, packed, table, ec = small
-    tel = SegmentTelemetry(warmup=0)
+    # the engine feeds no wall times (sample_every=0): the injected
+    # drift alone decides the remap, where a real sample in the floor
+    # window would make the outcome depend on the machine's speed
+    tel = SegmentTelemetry(warmup=0, sample_every=0)
     engine = ServingEngine(
         m, packed, ec, allowed_batch_sizes=table.batch_sizes,
         clock=FakeClock(), telemetry=tel,

@@ -152,3 +152,25 @@ def test_np_jnp_pack_agree():
     assert np.array_equal(
         np_pack_bits(x, 0), np.asarray(pack_bits(jnp.asarray(x), 0))
     )
+
+
+@pytest.mark.parametrize("n", [1, 31, 32, 33, 77, 130])
+@pytest.mark.parametrize("pad_bit", [0, 1])
+def test_pack_bits_int32_matches_uint32_packing(n, pad_bit):
+    """pack_bits packs in int32; the words are bit-identical to the
+    unsigned packing (sum of uint32 lane shifts, reinterpreted as
+    int32), pad lanes and the sign lane included."""
+    rng = np.random.default_rng(n * 2 + pad_bit)
+    bits = rng.random((5, n)) < 0.5
+    n_words = packed_len(n)
+    padded = np.concatenate(
+        [bits, np.full((5, n_words * 32 - n), bool(pad_bit))], axis=-1
+    ).reshape(5, n_words, 32)
+    shifts = jnp.arange(32, dtype=jnp.uint32)
+    want = jnp.sum(
+        jnp.asarray(padded).astype(jnp.uint32) << shifts,
+        axis=-1, dtype=jnp.uint32,
+    ).astype(jnp.int32)
+    got = pack_bits(jnp.asarray(bits), pad_bit)
+    assert got.dtype == jnp.int32
+    assert np.array_equal(np.asarray(want), np.asarray(got))

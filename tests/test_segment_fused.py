@@ -158,32 +158,29 @@ def test_scopes_partition_the_registry():
 
 
 def test_seg_pallas_applicability_caps():
+    def seg_names(shape, platform):
+        return {
+            v.name
+            for v in DEFAULT_REGISTRY.applicable_segments(shape, platform)
+        }
+
     small = SegmentShape(b=1, n_layers=3, work=1 << 10, vmem_bytes=1 << 20)
-    assert "seg_pallas" in {
-        v.name
-        for v in DEFAULT_REGISTRY.applicable_segments(small, "tpu")
-    }
+    assert "seg_pallas" in seg_names(small, "cpu")
     over_work = SegmentShape(
         b=1, n_layers=3,
         work=PALLAS_INTERPRET_MAX_WORK + 1, vmem_bytes=1 << 20,
     )
-    # interpret-mode cap binds off-TPU only
-    assert "seg_pallas" not in {
-        v.name
-        for v in DEFAULT_REGISTRY.applicable_segments(over_work, "cpu")
-    }
-    assert "seg_pallas" in {
-        v.name
-        for v in DEFAULT_REGISTRY.applicable_segments(over_work, "tpu")
-    }
+    # the interpret-mode work cap binds off-TPU
+    assert "seg_pallas" not in seg_names(over_work, "cpu")
     over_vmem = SegmentShape(
         b=1, n_layers=3, work=1 << 10,
         vmem_bytes=SEGMENT_VMEM_BUDGET + 1,
     )
-    assert "seg_pallas" not in {
-        v.name
-        for v in DEFAULT_REGISTRY.applicable_segments(over_vmem, "tpu")
-    }
+    assert "seg_pallas" not in seg_names(over_vmem, "cpu")
+    # Mosaic cannot compile the fused body: never offered on the TPU
+    for shape in (small, over_work, over_vmem):
+        assert "seg_pallas" not in seg_names(shape, "tpu")
+        assert "seg_xla" in seg_names(shape, "tpu")
     # seg_xla has no cap
     for shape in (small, over_work, over_vmem):
         assert "seg_xla" in {

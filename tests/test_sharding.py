@@ -37,9 +37,9 @@ _SUBPROC = textwrap.dedent("""
     from repro import configs as C
     from repro.models.transformer import init_params, forward
     from repro.parallel.sharding import make_param_shardings, make_batch_shardings, ShardScheme
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     cfg = C.get_smoke("olmo_1b")
     params = init_params(cfg, jax.random.PRNGKey(0))
     scheme = ShardScheme(tp=True, fsdp="zero1")
