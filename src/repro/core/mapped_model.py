@@ -42,6 +42,7 @@ from repro.bnn.models import BNNModel
 from repro.core.mapper import EfficientConfiguration
 from repro.core.plan import SegmentPlan, build_plan
 from repro.kernels.registry import DEFAULT_REGISTRY, SCOPE_SEGMENT
+from repro.tracing import span
 
 
 def _layer_fn(spec, packed, config: str, registry=None) -> Callable:
@@ -96,6 +97,15 @@ def _layer_fns(
     ]
 
 
+def node_name(k: int, node) -> str:
+    """Plan node `k`'s stable name, ``node<k>_<start>_<stop>_<variant>``
+    (the fused variant, else the placement): the name its executable is
+    jitted under, so the device trace's ``XLA Modules`` line reads
+    ``jit_<name>``, and the ``node`` of its spans."""
+    return (f"node{k}_{node.start}_{node.stop}_"
+            f"{node.fused_variant or node.placement}")
+
+
 def build_node_fns(
     model: BNNModel,
     packed_params: list,
@@ -104,7 +114,7 @@ def build_node_fns(
     registry=None,
 ) -> list:
     """One jitted callable per plan node, in execution order:
-    ``[(PlanNode, fn), ...]``.
+    ``[(PlanNode, fn), ...]``, each jitted under ``node_name``.
 
     A node carrying a ``fused_variant`` resolves that segment-scope
     variant's builder over the node's layer slice (one fused dispatch,
@@ -114,7 +124,7 @@ def build_node_fns(
     reg = registry if registry is not None else DEFAULT_REGISTRY
     fns = _layer_fns(model, packed_params, config, registry)
     out = []
-    for node in plan.nodes:
+    for k, node in enumerate(plan.nodes):
         if node.fused_variant is not None:
             variant = reg.get(node.fused_variant)
             if variant.scope != SCOPE_SEGMENT:
@@ -130,20 +140,33 @@ def build_node_fns(
             )
         else:
             fn = _compose(fns[node.start:node.stop])
-        out.append((node, fn))
+        out.append((node, _jit_named(fn, node_name(k, node))))
     return out
 
 
 def _compose(layer_fns) -> Callable:
     layer_fns = tuple(layer_fns)
 
-    @jax.jit
     def fn(x):
         for f in layer_fns:
             x = f(x)
         return x
 
     return fn
+
+
+def _jit_named(fn, name: str) -> Callable:
+    """`fn` under ``jax.jit`` as `name`.  A function a builder already
+    jitted is jitted again from the function it wraps, so the node is
+    still one dispatch of the same operations."""
+    if hasattr(fn, "lower") and hasattr(fn, "__wrapped__"):
+        fn = fn.__wrapped__
+
+    def node(x):
+        return fn(x)
+
+    node.__name__ = node.__qualname__ = name
+    return jax.jit(node)
 
 
 def run_plan(node_fns, *, device=None) -> Callable:
@@ -154,18 +177,31 @@ def run_plan(node_fns, *, device=None) -> Callable:
     measured), D2H (``np.asarray``) after a ``transfer_out`` node.
     Between co-placed nodes the activation stays where it is."""
     dev = device if device is not None else jax.devices()[0]
+    names = [node_name(k, node) for k, (node, _) in enumerate(node_fns)]
 
     def run(x_words):
         x = np.asarray(x_words)          # input starts on the host
-        for node, fn in node_fns:
+        batch = x.shape[0]
+        for name, (node, fn) in zip(names, node_fns):
             if node.transfer_in and not isinstance(x, jax.Array):
-                x = jax.device_put(x, dev)
-            out = fn(x)
+                with span("pipeline.h2d", batch=batch):
+                    x = jax.device_put(x, dev)
+            with span("pipeline.dispatch", node=name, batch=batch):
+                out = fn(x)
             jax.block_until_ready(out)
-            x = np.asarray(out) if node.transfer_out else out
-        return np.asarray(x)
+            x = to_host(out, name, batch) if node.transfer_out else out
+        return to_host(x, names[-1], batch)
 
     return run
+
+
+def to_host(x, node: str, batch: int) -> np.ndarray:
+    """``np.asarray(x)``; for a device result that is the D2H wait, and
+    it is spanned as ``pipeline.d2h`` of `node`."""
+    if not isinstance(x, jax.Array):
+        return np.asarray(x)
+    with span("pipeline.d2h", node=node, batch=batch):
+        return np.asarray(x)
 
 
 def build_mapped_model(

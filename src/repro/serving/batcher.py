@@ -33,6 +33,8 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.tracing import span
+
 
 @dataclasses.dataclass
 class Request:
@@ -175,11 +177,12 @@ class MicroBatcher:
                 return None
             take = min(len(self._queue), self.max_batch)
             reqs = tuple(self._queue.popleft() for _ in range(take))
-        xs = np.stack([r.x for r in reqs])
         target = pad_to(len(reqs), self.allowed_batch_sizes)
-        if target > len(reqs):
-            pad = np.zeros((target - len(reqs),) + xs.shape[1:], xs.dtype)
-            xs = np.concatenate([xs, pad])
+        with span("batcher.form", n_real=len(reqs), padded=target):
+            xs = np.stack([r.x for r in reqs])
+            if target > len(reqs):
+                pad = np.zeros((target - len(reqs),) + xs.shape[1:], xs.dtype)
+                xs = np.concatenate([xs, pad])
         return MicroBatch(requests=reqs, x=xs, n_real=len(reqs))
 
     def drain(self, *, force: bool = True) -> list:

@@ -48,6 +48,7 @@ from repro.bnn.models import BNNModel
 from repro.core.mapper import EfficientConfiguration
 from repro.serving.batcher import MicroBatcher, Request
 from repro.serving.pipeline import SegmentPipeline
+from repro.tracing import span
 
 
 def _tee(always, sampled):
@@ -184,51 +185,56 @@ class ServingEngine:
         An empty queue is a no-op even under ``force`` — the batcher
         never fabricates a zero batch to pad-and-run (regression:
         ``tests/test_adapt.py``), and a pending swap still applies."""
-        batches = self.batcher.drain(force=force)
-        if not batches:
+        with span("engine.step", step=self.steps) as sp:
+            batches = self.batcher.drain(force=force)
+            if not batches:
+                self._drain_pending_swap()
+                return 0
+            done = sum(mb.n_real for mb in batches)
+            sp.set_metadata(batches=len(batches), images=done)
+
+            def complete(i, out):
+                mb = batches[i]
+                with span("engine.complete", n_real=mb.n_real):
+                    now = self._clock()
+                    for j, req in enumerate(mb.requests):
+                        req.complete(out[j], now)   # pad rows dropped
+
+            observer = None
+            if self.telemetry is not None:
+                observer = self.telemetry.sample()
+            if self.observer is not None:
+                observer = _tee(self.observer, observer)
+            self._in_step = True
+            try:
+                self.pipeline.run_pipelined(
+                    [mb.x for mb in batches],
+                    on_complete=complete,
+                    observer=observer,
+                )
+            except BaseException as e:
+                # requests already popped off the queue must not be
+                # lost: fail every not-yet-completed one so waiters see
+                # the error.  A pending swap stays pending (applied at
+                # the next batch boundary) — applying it here could
+                # raise a build error that masks the serving failure
+                # being diagnosed
+                now = self._clock()
+                for mb in batches:
+                    for req in mb.requests:
+                        if req.done_t is None:
+                            req.fail(e, now)
+                raise
+            finally:
+                self._in_step = False
+            self.served += done
+            self.steps += 1
+            # the batch boundary: a swap requested mid-step lands here,
+            # after the step's work is fully accounted — a failed
+            # pipeline build raises from step() but never corrupts
+            # served/steps
             self._drain_pending_swap()
-            return 0
-
-        def complete(i, out):
-            mb = batches[i]
-            now = self._clock()
-            for j, req in enumerate(mb.requests):
-                req.complete(out[j], now)   # pad rows out[n_real:] dropped
-
-        observer = None
-        if self.telemetry is not None:
-            observer = self.telemetry.sample()
-        if self.observer is not None:
-            observer = _tee(self.observer, observer)
-        self._in_step = True
-        try:
-            self.pipeline.run_pipelined(
-                [mb.x for mb in batches],
-                on_complete=complete,
-                observer=observer,
-            )
-        except BaseException as e:
-            # requests already popped off the queue must not be lost:
-            # fail every not-yet-completed one so waiters see the error.
-            # A pending swap stays pending (applied at the next batch
-            # boundary) — applying it here could raise a build error
-            # that masks the serving failure being diagnosed
-            now = self._clock()
-            for mb in batches:
-                for req in mb.requests:
-                    if req.done_t is None:
-                        req.fail(e, now)
-            raise
-        finally:
-            self._in_step = False
-        done = sum(mb.n_real for mb in batches)
-        self.served += done
-        self.steps += 1
-        # the batch boundary: a swap requested mid-step lands here,
-        # after the step's work is fully accounted — a failed pipeline
-        # build raises from step() but never corrupts served/steps
-        self._drain_pending_swap()
-        return done
+            return done
 
     def _drain_pending_swap(self) -> None:
         if self._pending_swap is not None:

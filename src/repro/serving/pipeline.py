@@ -53,10 +53,11 @@ import jax
 import numpy as np
 
 from repro.bnn.models import BNNModel
-from repro.core.mapped_model import build_node_fns
+from repro.core.mapped_model import build_node_fns, node_name, to_host
 from repro.core.mapper import EfficientConfiguration
 from repro.core.parallel_config import CPU, FULL_GPU
 from repro.core.plan import SegmentPlan, build_plan
+from repro.tracing import span
 
 
 def canonical_mixed_mapping(model: BNNModel) -> tuple:
@@ -107,6 +108,9 @@ class SegmentPipeline:
         self.segment_fns = build_node_fns(
             model, packed_params, config, plan, registry
         )
+        self.node_names = [
+            node_name(k, node) for k, (node, _) in enumerate(self.segment_fns)
+        ]
         self.device = device if device is not None else jax.devices()[0]
 
     @property
@@ -119,18 +123,19 @@ class SegmentPipeline:
         x = np.asarray(x_words)
         batch = x.shape[0]
         for s, (seg, fn) in enumerate(self.segment_fns):
+            name = self.node_names[s]
             t0 = time.perf_counter() if observer is not None else 0.0
             if seg.on_device:
-                out = fn(jax.device_put(x, self.device))
-                jax.block_until_ready(out)
-                x = np.asarray(out)          # D2H before the next segment
-            else:
+                with span("pipeline.h2d", batch=batch):
+                    x = jax.device_put(x, self.device)
+            with span("pipeline.dispatch", node=name, batch=batch):
                 out = fn(x)
-                jax.block_until_ready(out)
-                x = out
+            jax.block_until_ready(out)
+            # D2H after a device segment, before the next one
+            x = to_host(out, name, batch) if seg.on_device else out
             if observer is not None:
                 observer(s, seg, time.perf_counter() - t0, batch)
-        return np.asarray(x)
+        return to_host(x, self.node_names[-1], batch)
 
     # -- pipelined driver over a micro-batch stream ------------------
     def run_pipelined(
@@ -159,6 +164,7 @@ class SegmentPipeline:
         if n == 0:
             return []
         first_on_device = segs[0][0].on_device
+        names = self.node_names
         state: list = [None] * n
         staged: list = [None] * n
         outputs: list = [None] * n
@@ -167,9 +173,10 @@ class SegmentPipeline:
             # double-buffered H2D: the upload is issued a wave before
             # micro-batch i first executes
             x = np.asarray(inputs[i])
-            staged[i] = (
-                jax.device_put(x, self.device) if first_on_device else x
-            )
+            if first_on_device:
+                with span("pipeline.h2d", batch=x.shape[0]):
+                    x = jax.device_put(x, self.device)
+            staged[i] = x
 
         stage(0)
         for w in range(n + k - 1):
@@ -186,18 +193,18 @@ class SegmentPipeline:
                 if seg.on_device:
                     x = staged[i] if s == 0 else state[i]
                     staged[i] = None        # keep only ~2 live buffers
+                    batch = x.shape[0]
                     if not isinstance(x, jax.Array):
-                        x = jax.device_put(x, self.device)
-                    if observer is None:
-                        state[i] = fn(x)
-                    else:
-                        t0 = time.perf_counter()
+                        with span("pipeline.h2d", batch=batch):
+                            x = jax.device_put(x, self.device)
+                    t0 = time.perf_counter() if observer is not None else 0.0
+                    with span("pipeline.dispatch", node=names[s],
+                              batch=batch):
                         out = fn(x)
+                    if observer is not None:
                         jax.block_until_ready(out)
-                        observer(
-                            s, seg, time.perf_counter() - t0, x.shape[0]
-                        )
-                        state[i] = out
+                        observer(s, seg, time.perf_counter() - t0, batch)
+                    state[i] = out
             # host advances: np.asarray is the deferred D2H sync on the
             # previous wave's device output
             for i, s in active:
@@ -205,24 +212,25 @@ class SegmentPipeline:
                 if not seg.on_device:
                     x = staged[i] if s == 0 else state[i]
                     staged[i] = None
-                    if observer is None:
-                        state[i] = fn(np.asarray(x))
-                    else:
-                        # timing includes the deferred D2H sync of the
-                        # upstream device output — the host stage pays
-                        # it in the un-instrumented driver too
-                        t0 = time.perf_counter()
-                        xh = np.asarray(x)
-                        out = fn(xh)
+                    batch = x.shape[0]
+                    # with an observer the timing includes the deferred
+                    # D2H sync of the upstream device output: the host
+                    # stage pays it in the un-instrumented driver too
+                    t0 = time.perf_counter() if observer is not None else 0.0
+                    if s > 0:
+                        x = to_host(x, names[s - 1], batch)
+                    with span("pipeline.dispatch", node=names[s],
+                              batch=batch):
+                        out = fn(x)
+                    if observer is not None:
                         jax.block_until_ready(out)
-                        observer(
-                            s, seg, time.perf_counter() - t0, xh.shape[0]
-                        )
-                        state[i] = out
+                        observer(s, seg, time.perf_counter() - t0, batch)
+                    state[i] = out
             # completions: micro-batch i leaves the pipeline
             for i, s in active:
                 if s == k - 1:
-                    outputs[i] = np.asarray(state[i])
+                    outputs[i] = to_host(state[i], names[s],
+                                         state[i].shape[0])
                     state[i] = None
                     if on_complete is not None:
                         on_complete(i, outputs[i])
