@@ -18,10 +18,11 @@ is exactly the regime segment fusion targets; at larger batches the
 GEMM work amortizes the tax and the two paths converge.
 
 For each batch size and each applicable segment-scope variant
-(``seg_xla`` always; ``seg_pallas`` off the TPU, when the segment fits
-the interpret work cap / VMEM budget), the bench asserts the fused output
-bit-exact against the per-layer chain (and against the model's
-reference ``forward_packed``), then times best-of-``repeats``.
+(``seg_xla`` always; ``seg_mxu`` on the TPU; ``seg_pallas`` off the
+TPU, when the segment fits the interpret work cap / VMEM budget), the
+bench asserts the fused output bit-exact against the per-layer chain
+(and against the model's reference ``forward_packed``), then times
+best-of-``repeats``.
 
 Rows (``us_per_call`` is us per **example**):
 

@@ -32,6 +32,7 @@ either way, since all arithmetic is integer/bool.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
@@ -158,15 +159,26 @@ def _compose(layer_fns) -> Callable:
 def _jit_named(fn, name: str) -> Callable:
     """`fn` under ``jax.jit`` as `name`.  A function a builder already
     jitted is jitted again from the function it wraps, so the node is
-    still one dispatch of the same operations."""
+    still one dispatch of the same operations.  Operands a builder
+    bound with ``functools.partial`` (its weights) stay arguments of
+    the node, so the node's executable does not depend on their
+    values."""
+    operands = ()
+    if isinstance(fn, functools.partial):
+        fn, operands = fn.func, fn.args
     if hasattr(fn, "lower") and hasattr(fn, "__wrapped__"):
         fn = fn.__wrapped__
 
-    def node(x):
-        return fn(x)
+    if operands:
+        def node(operands, x):
+            return fn(*operands, x)
+    else:
+        def node(x):
+            return fn(x)
 
     node.__name__ = node.__qualname__ = name
-    return jax.jit(node)
+    jitted = jax.jit(node)
+    return functools.partial(jitted, operands) if operands else jitted
 
 
 def run_plan(node_fns, *, device=None) -> Callable:

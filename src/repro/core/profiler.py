@@ -579,6 +579,10 @@ def profile_segment_variants(
     segment's boundary transfers are unchanged by fusion (same edge
     operands) and stay priced by the per-layer h2d/d2h rows.
 
+    A variant the table already has a row for at that span and batch
+    (a stored profile's) keeps its row and is not timed again, so a
+    warm start compiles no segment executable.
+
     Spans must be device-resident layer runs of the profiled model —
     typically ``core.plan.device_spans(config)``.
     """
@@ -596,20 +600,15 @@ def profile_segment_variants(
                 f"batch {batch} not profiled (have {table.batch_sizes})"
             )
         layer_inputs = None
-        if time_source == "measured":
-            x01 = jax.random.uniform(
-                key, (batch, *model.input_hw, model.in_channels)
-            )
-            x_words = prepare_input_packed(x01)
-            layer_inputs = _capture_layer_inputs(
-                model, packed_params, x_words
-            )
         for start, stop in spans:
             specs = tuple(model.specs[start:stop])
             pp = list(packed_params[start:stop])
             shape = segment_shape_of(specs, pp, batch)
+            timed = set(table.segment_variants_for(batch, start, stop))
             row = {}
             for v in reg.applicable_segments(shape, platform):
+                if v.name in timed:
+                    continue
                 if time_source == "analytic":
                     if v.analytic == "fused":
                         t = cm.fused_segment_kernel_time_tpu(specs, batch)
@@ -618,6 +617,13 @@ def profile_segment_variants(
                             specs, batch, registry=reg
                         )
                 else:
+                    if layer_inputs is None:
+                        x01 = jax.random.uniform(
+                            key, (batch, *model.input_hw, model.in_channels)
+                        )
+                        layer_inputs = _capture_layer_inputs(
+                            model, packed_params, prepare_input_packed(x01)
+                        )
                     fn = v.builder(specs, pp)
                     x_in = layer_inputs[start]
                     t = _timeit(lambda: fn(x_in), repeats)

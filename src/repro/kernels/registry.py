@@ -49,6 +49,7 @@ import jax
 
 from repro.kernels.ref import xnor_gemm_ref
 from repro.kernels.segment_fused import (
+    build_mxu_segment,
     build_pallas_segment,
     build_xla_segment,
     infer_in_encoding,
@@ -324,6 +325,11 @@ def _seg_xla_applicable(shape: SegmentShape, platform: str) -> bool:
     return True
 
 
+def _seg_mxu_applicable(shape: SegmentShape, platform: str) -> bool:
+    # the int8 GEMMs are worth timing only where the MXU runs them
+    return platform == "tpu"
+
+
 def _register_defaults(reg: VariantRegistry) -> VariantRegistry:
     reg.register(
         KernelVariant(
@@ -391,6 +397,22 @@ def _register_defaults(reg: VariantRegistry) -> VariantRegistry:
             description="whole segment as one XLA executable — the "
             "layer chain jitted together, threshold/repack fused into "
             "the GEMM tails",
+        )
+    )
+    reg.register(
+        KernelVariant(
+            name="seg_mxu",
+            builder=build_mxu_segment,
+            placement=DEVICE,
+            scope=SCOPE_SEGMENT,
+            aspects=("X", "Y", "Z"),
+            # priced like seg_xla, registered after it: analytic ties
+            # keep seg_xla, and only a measured profile picks this one
+            analytic="tiled",
+            applicable=_seg_mxu_applicable,
+            description="whole segment as one XLA executable with the "
+            "±1 products as int8 convolutions/matmuls (the MXU), "
+            "weights unpacked to int8 once",
         )
     )
     reg.register(

@@ -8,7 +8,7 @@ FINN / Larq-CE-style engines get their headline BNN wins by *fusing*
 that chain: GEMM -> threshold -> repack happens in on-chip memory and
 the segment's interior activations never materialize off-chip.
 
-Two segment-scope builders, registered as ``KernelVariant``\\ s
+Three segment-scope builders, registered as ``KernelVariant``\\ s
 (``scope="segment"``) so the profiler, DP mapper and serving runtime
 price and select them like any other variant:
 
@@ -28,9 +28,19 @@ price and select them like any other variant:
   one-example blocks, so the registry does not offer it on the TPU
   (``kernels/registry.py``).
 
-Both builders compute the exact reference semantics (they reuse the
-``repro.bnn.layers`` packed ops on a per-example block), so fused
-execution is bit-exact against per-layer execution by construction.
+* ``seg_mxu`` — the same chain in a ±1 int8 domain: each conv / fc
+  is an int8 x int8 -> int32 convolution or matmul (the MXU on a
+  TPU) over weights unpacked once at build time, each step writes
+  ±1 int8, and packed words appear only at the segment's edges.
+  Offered on the TPU only (``kernels/registry.py``).
+
+``seg_xla`` and ``seg_pallas`` compute the exact reference semantics
+(they reuse the ``repro.bnn.layers`` packed ops on a per-example
+block), so fused execution is bit-exact against per-layer execution by
+construction.  ``seg_mxu`` is exact too: a sum of at most 8192
+products of ±1 values is an integer that int32 holds, and the
+binary-domain pad (−1) and dropped tail lanes match the packed
+convention (``repro.bnn.binarize``).
 
 Builder signature (segment scope): ``builder(specs, packed_params,
 in_encoding=None) -> fn(x) -> out`` over the segment's layer slice.
@@ -51,7 +61,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.bnn import layers as L
-from repro.bnn.binarize import PACK_W
+from repro.bnn.binarize import PACK_W, pack_bits
 
 PACKED = "packed"
 UNPACKED = "unpacked"
@@ -188,6 +198,104 @@ def build_xla_segment(
         return _run_chain(specs, packed_params, x)
 
     return run
+
+
+# ---------------------------------------------------------------------------
+# seg_mxu: the segment chain with its ±1 products as int8 GEMMs
+# ---------------------------------------------------------------------------
+
+
+def _unpack_pm1(words: jax.Array, n: int) -> jax.Array:
+    """Packed words ``(..., Kw)`` -> int8 ±1 ``(..., n)``, tail lanes
+    dropped."""
+    bits = (words[..., None] >> jnp.arange(PACK_W, dtype=jnp.int32)) & 1
+    bits = bits.reshape(words.shape[:-1] + (-1,))[..., :n]
+    return (2 * bits - 1).astype(jnp.int8)
+
+
+def _mxu_params(specs: Sequence[L.LayerSpec], packed_params) -> list:
+    """Each conv's ``w_words (Cout, 9*Cw)`` as HWIO ``(3, 3, Cin,
+    Cout)`` int8 ±1, each fc's ``(Dout, Kw)`` as ``(Din, Dout)``: the
+    patch order of ``extract_patch_words`` (dy-major, dx-minor), tail
+    lanes dropped."""
+    out = []
+    for spec, p in zip(specs, packed_params):
+        if spec.kind == "conv":
+            cin = spec.in_shape[-1]
+            w = jnp.asarray(p["w_words"])
+            w = _unpack_pm1(w.reshape(w.shape[0], 9, -1), cin)
+            out.append({"w": w.reshape(-1, 3, 3, cin).transpose(1, 2, 3, 0)})
+        elif spec.kind == "fc":
+            w = _unpack_pm1(jnp.asarray(p["w_words"]), spec.in_shape[0])
+            out.append({"w": w.T})
+        elif spec.kind == "step":
+            out.append({"thresh": p["thresh"], "flip": p["flip"]})
+        else:
+            out.append({})
+    return out
+
+
+_PM1 = "pm1"  # int8 ±1 activations, one per lane
+
+
+def _run_mxu_chain(specs, params, x, form: str):
+    """The segment chain on int8 ±1 activations.  `form` is what `x`
+    holds: packed words, unpacked int32 sums, or ±1 lanes."""
+    for spec, p in zip(specs, params):
+        if spec.kind in ("conv", "fc"):
+            if form == PACKED:
+                x = _unpack_pm1(x, spec.in_shape[-1])
+            if spec.kind == "conv":
+                x = jnp.pad(
+                    x, ((0, 0), (1, 1), (1, 1), (0, 0)), constant_values=-1
+                )
+                x = jax.lax.conv_general_dilated(
+                    x, p["w"], (1, 1), "VALID",
+                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                    preferred_element_type=jnp.int32,
+                )
+            else:
+                x = jnp.dot(x, p["w"], preferred_element_type=jnp.int32)
+            form = UNPACKED
+        elif spec.kind == "mp":
+            if form == _PM1:
+                # pool what the packed chain pools: the words
+                x, form = pack_bits(x), PACKED
+            x = L.maxpool_packed(x)
+        elif spec.kind == "step":
+            bits = (x > p["thresh"]) ^ p["flip"]
+            x = jnp.where(bits, jnp.int8(1), jnp.int8(-1))
+            form = _PM1
+        elif spec.kind == "flat":
+            # C % 32 == 0 here, so lanes and words flatten alike
+            x = x.reshape(x.shape[0], -1)
+        else:
+            raise ValueError(spec.kind)
+    return pack_bits(x) if form == _PM1 else x
+
+
+def build_mxu_segment(
+    specs: Sequence[L.LayerSpec],
+    packed_params,
+    in_encoding: str | None = None,
+):
+    """One jitted executable for the segment whose conv / fc products
+    are int8 x int8 -> int32 GEMMs on ±1 values.  Weights are unpacked
+    to int8 here, once, and bound with ``functools.partial`` as the
+    executable's operands rather than folded in as constants, so its
+    compiled form does not depend on their values (one compile-cache
+    entry serves every set of weights).  The segment's input and output
+    keep the packed chain's encodings, and its int32 results equal
+    ``seg_xla``'s bit for bit."""
+    specs = tuple(specs)
+    if in_encoding is None:
+        in_encoding = infer_in_encoding(specs)
+
+    @jax.jit
+    def run(params, x):
+        return _run_mxu_chain(specs, params, x, in_encoding)
+
+    return functools.partial(run, _mxu_params(specs, packed_params))
 
 
 # ---------------------------------------------------------------------------

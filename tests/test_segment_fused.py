@@ -5,6 +5,7 @@ fused-vs-per-layer selection (analytic and measured)."""
 
 from __future__ import annotations
 
+import functools
 import json
 
 import jax
@@ -12,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.bnn import build_model
+from repro.bnn import layers as L
 from repro.bnn.models import forward_packed, pack_params, prepare_input_packed
 from repro.core.mapped_model import build_node_fns, build_segment_fns
 from repro.core.mapper import (
@@ -25,6 +27,7 @@ from repro.core.plan import (
     build_plan,
     device_spans,
     fuse_configuration,
+    fuse_mapping,
     select_fused_segments,
 )
 from repro.core.profiler import (
@@ -43,6 +46,7 @@ from repro.kernels.registry import (
     segment_shape_of,
 )
 from repro.kernels.segment_fused import (
+    build_mxu_segment,
     build_pallas_segment,
     build_xla_segment,
     encoded_shape,
@@ -136,6 +140,124 @@ def test_registry_applicable_segments_bitexact():
 
 
 # ---------------------------------------------------------------------------
+# seg_mxu: int8 GEMMs, bit-exact against seg_xla at published widths
+# ---------------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _published(name):
+    """A published-width model whose step layers flip and threshold
+    away from 0, so every ±1 of the int8 domain is exercised."""
+    m = build_model(name)
+    key = jax.random.PRNGKey(0)
+    params = m.init(key)
+    for spec, p in zip(m.specs, params):
+        if spec.kind == "step":
+            key, k1, k2, k3 = jax.random.split(key, 4)
+            p["gamma"] = jax.random.normal(k1, p["gamma"].shape)
+            p["beta"] = jax.random.normal(k2, p["beta"].shape)
+            p["mean"] = jax.random.normal(k3, p["mean"].shape) * 5
+    return m, pack_params(m.specs, params)
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_inputs(name, batch):
+    """Each layer's input, then the scores: ``forward_packed`` one
+    layer at a time, so ``xs[stop]`` is the reference at any cut."""
+    m, packed = _published(name)
+    x = prepare_input_packed(
+        jax.random.uniform(
+            jax.random.PRNGKey(batch), (batch, *m.input_hw, m.in_channels)
+        )
+    )
+    xs = [x]
+    for i in range(len(m.specs)):
+        xs.append(forward_packed(m.specs[i:i + 1], packed[i:i + 1], xs[-1]))
+    return xs
+
+
+def _mxu_span(specs, which):
+    """The spans the MXU chain must match on: the whole model, a head
+    that ends packed after S/FLAT (border and Cin-tail lanes of the
+    first conv), a span that starts packed at a later conv, and spans
+    that start unpacked at MP or S."""
+    kinds = [s.kind for s in specs]
+    n = len(specs)
+    flat = kinds.index("flat")
+    if which == "whole":
+        return 0, n
+    if which == "head_to_flat":
+        return 0, flat + 1
+    if which == "from_conv":
+        return kinds.index("conv", 1), n
+    if which == "from_mp":
+        return kinds.index("mp"), n
+    return kinds.index("step"), n
+
+
+@pytest.mark.parametrize("batch", [1, 5])
+@pytest.mark.parametrize("name", ["fashion_mnist", "cifar10"])
+@pytest.mark.parametrize(
+    "which", ["whole", "head_to_flat", "from_conv", "from_mp", "from_step"]
+)
+def test_mxu_segment_bitexact_published_widths(name, batch, which):
+    m, packed = _published(name)
+    start, stop = _mxu_span(m.specs, which)
+    specs, pp = tuple(m.specs[start:stop]), list(packed[start:stop])
+    xs = _layer_inputs(name, batch)
+    enc = infer_in_encoding(specs)
+    got = np.asarray(build_mxu_segment(specs, pp, enc)(xs[start]))
+    want = np.asarray(xs[stop])
+    assert got.dtype == want.dtype == np.int32
+    assert np.array_equal(got, want)
+    assert np.array_equal(
+        got, np.asarray(build_xla_segment(specs, pp, enc)(xs[start]))
+    )
+
+
+def test_mxu_segment_pads_with_minus_one():
+    """An all-(+1) image through one conv: a ±1 sum over the 3x3 window
+    counts the border pixels as −1, so corner, edge and interior
+    outputs differ, exactly as the packed conv reads them."""
+    m, packed = _published("cifar10")
+    spec, p = m.specs[0], packed[0]
+    x = prepare_input_packed(np.ones((1, *m.input_hw, m.in_channels)))
+    got = np.asarray(build_mxu_segment((spec,), [p])(x))
+    want = np.asarray(L.conv_packed(x, p["w_words"], p["k_true"]))
+    assert np.array_equal(got, want)
+    assert not np.array_equal(got[0, 0, 0], got[0, 1, 1])
+
+
+@pytest.mark.parametrize("lead", ["mp", "step_mp"])
+def test_mxu_segment_pools_words_like_the_packed_chain(lead):
+    """MP on packed words (a span that starts there, or MP after S)
+    pools the words, as the packed chain does, where pooling ±1 lanes
+    would OR them: seg_mxu still equals seg_xla."""
+    m, packed = _published("cifar10")
+    conv_i = [i for i, s in enumerate(m.specs) if s.kind == "conv"][1]
+    conv, step = m.specs[conv_i], m.specs[conv_i - 1]
+    h, w, c = conv.in_shape
+    big = (2 * h, 2 * w, c)
+    mp = L.LayerSpec(0, "mp", f"MP{h}", big, conv.in_shape, c)
+    key = jax.random.PRNGKey(3)
+    if lead == "mp":
+        span, pp, enc = (mp, conv), [{}, packed[conv_i]], PACKED
+        x = jax.random.randint(
+            key, (2, 2 * h, 2 * w, c // 32), -(2**31), 2**31 - 1, "int32"
+        )
+    else:
+        s_big = L.LayerSpec(0, "step", "S", big, big, c)
+        span = (s_big, mp, conv)
+        pp = [packed[conv_i - 1], {}, packed[conv_i]]
+        enc = UNPACKED
+        assert step.kind == "step" and step.units == c
+        x = jax.random.randint(key, (2, *big), -40, 40, "int32")
+    got = build_mxu_segment(span, pp, enc)(x)
+    want = build_xla_segment(span, pp, enc)(x)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
 # Registry scope rules
 # ---------------------------------------------------------------------------
 
@@ -189,6 +311,37 @@ def test_seg_pallas_applicability_caps():
         }
 
 
+def test_seg_mxu_offered_only_on_the_tpu():
+    """The int8 GEMMs are timed only where the MXU runs them: CPU plans
+    never see seg_mxu."""
+    for shape in (
+        SegmentShape(b=1, n_layers=3, work=1 << 10, vmem_bytes=1 << 20),
+        SegmentShape(b=256, n_layers=19, work=1 << 40, vmem_bytes=1 << 30),
+    ):
+        on = {v.name for v in DEFAULT_REGISTRY.applicable_segments(shape, "tpu")}
+        off = {v.name for v in DEFAULT_REGISTRY.applicable_segments(shape, "cpu")}
+        assert "seg_mxu" in on
+        assert "seg_mxu" not in off
+    v = DEFAULT_REGISTRY.get("seg_mxu")
+    assert (v.scope, v.placement) == (SCOPE_SEGMENT, "device")
+    names = DEFAULT_REGISTRY.segment_names()
+    assert names.index("seg_xla") < names.index("seg_mxu")
+
+
+def test_analytic_fusion_keeps_seg_xla_on_ties():
+    """Analytic pricing (platform "tpu") prices seg_mxu as seg_xla; the
+    tie keeps seg_xla, so analytic plans are what they were."""
+    m, packed, x = _setup("fashion_mnist")
+    table, ec = _mixed_ec(m, packed)
+    fused = fuse_mapping(m, packed, table, ec, time_source="analytic")
+    assert fused.fused_segments
+    for start, stop, name, _ in fused.fused_segments:
+        assert name == "seg_xla"
+        assert table.segment_time(2, start, stop, "seg_mxu") == (
+            table.segment_time(2, start, stop, "seg_xla")
+        )
+
+
 # ---------------------------------------------------------------------------
 # Segment-row profiling + selection
 # ---------------------------------------------------------------------------
@@ -227,6 +380,40 @@ def test_profile_segment_variants_stores_rows_and_roundtrips():
     assert again.segment_times == table.segment_times
     with pytest.raises(KeyError):
         table.segment_time(2, 0, 1, "seg_xla")
+
+
+def test_stored_segment_rows_are_not_timed_again(monkeypatch):
+    """A warm start (the table already holds a span's rows) measures
+    nothing: no layer inputs are captured and no variant is built.
+    A variant without a row is still timed."""
+    import repro.core.profiler as profiler
+
+    m, packed, x = _setup("fashion_mnist")
+    table, ec = _mixed_ec(m, packed)
+    spans = device_spans(ec)
+    for start, stop in spans:
+        shape = segment_shape_of(m.specs[start:stop], packed[start:stop], 2)
+        table.add_segment_row(2, start, stop, {
+            v.name: 7.0
+            for v in DEFAULT_REGISTRY.applicable_segments(shape, "cpu")
+        })
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("a stored row was timed again")
+
+    monkeypatch.setattr(profiler, "_capture_layer_inputs", refuse)
+    monkeypatch.setattr(profiler, "_timeit", refuse)
+    profile_segment_variants(
+        m, packed, table, spans=spans, batch_sizes=(2,),
+        time_source="measured", platform="cpu",
+    )
+    profile_segment_variants(
+        m, packed, table, spans=spans, batch_sizes=(2,),
+        time_source="analytic",
+    )
+    for start, stop in spans:
+        assert table.segment_time(2, start, stop, "seg_xla") == 7.0
+        assert table.segment_time(2, start, stop, "seg_mxu") > 0.0
 
 
 def test_unprofiled_batch_rejected():
@@ -329,3 +516,36 @@ def test_layer_scope_variant_rejected_as_fused():
     plan = build_plan(bad, mode="segments")
     with pytest.raises(ValueError, match="scope"):
         build_node_fns(m, packed, bad, plan)
+
+
+def test_mxu_node_keeps_its_weights_as_operands():
+    """A plan node served by seg_mxu is jitted under its node name with
+    the int8 weights as arguments, not constants: two sets of weights
+    lower to one program (one compile-cache entry), and each node
+    answers bit for bit."""
+    import dataclasses
+
+    from repro.core.mapped_model import node_name
+
+    m = build_model("fashion_mnist", scale=0.25)
+    x = _setup("fashion_mnist")[2]
+    texts = []
+    for seed in (0, 1):
+        packed = pack_params(m.specs, m.init(jax.random.PRNGKey(seed)))
+        table, ec = _mixed_ec(m, packed)
+        (start, stop) = device_spans(ec)[0]
+        fused = dataclasses.replace(
+            ec, fused_segments=((start, stop, "seg_mxu", 1e-6),)
+        )
+        y = x
+        for k, (node, fn) in enumerate(build_node_fns(
+            m, packed, fused, build_plan(fused, mode="segments")
+        )):
+            if node.fused_variant == "seg_mxu":
+                text = fn.func.lower(*fn.args, y).as_text()
+                assert f"jit_{node_name(k, node)}" in text
+                texts.append(text)
+            y = fn(y)
+        want = forward_packed(m.specs, packed, x)
+        assert np.array_equal(np.asarray(y), np.asarray(want))
+    assert len(texts) == 2 and texts[0] == texts[1]

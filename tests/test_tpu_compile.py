@@ -7,8 +7,9 @@ paper models' published widths:
 
 * every Pallas layer variant applicable on ``"tpu"`` compiles natively,
   and every one reported not applicable is one Mosaic refuses;
-* ``seg_pallas`` is not applicable on ``"tpu"``, and ``seg_xla``, which
-  serves fused device segments there, compiles.
+* ``seg_pallas`` is not applicable on ``"tpu"``; ``seg_xla`` and
+  ``seg_mxu``, which serve fused device segments there, compile, the
+  latter with its ±1 products as int8 convolutions and no popcount.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and under several
@@ -18,6 +19,7 @@ chip cannot be read back without one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +31,7 @@ from repro.bnn.models import pack_params
 from repro.core.profiler import gemm_shape_of
 from repro.kernels.registry import DEFAULT_REGISTRY, segment_shape_of
 from repro.kernels.segment_fused import (
+    build_mxu_segment,
     build_pallas_segment,
     build_xla_segment,
     encoded_shape,
@@ -141,7 +144,8 @@ def test_device_segment_compiles_and_seg_pallas_stays_off(
 ):
     """The segment bench's span (first conv on the host, the rest on
     the device): ``seg_pallas`` is not offered on the TPU, because
-    Mosaic cannot compile its fused body, and ``seg_xla`` compiles."""
+    Mosaic cannot compile its fused body; ``seg_xla`` and ``seg_mxu``
+    compile."""
     m, packed = models[name]
     specs = tuple(m.specs[1:])
     pp = list(packed[1:])
@@ -151,13 +155,55 @@ def test_device_segment_compiles_and_seg_pallas_stays_off(
             segment_shape_of(specs, pp, BATCH), "tpu"
         )
     }
-    assert names == {"seg_xla"}
+    assert names == {"seg_xla", "seg_mxu"}
     enc = infer_in_encoding(specs)
     x = jax.ShapeDtypeStruct(
         (BATCH,) + encoded_shape(specs[0].in_shape, enc),
         jnp.int32, sharding=one_chip,
     )
     build_xla_segment(specs, pp, enc).lower(x).compile()
+    _assert_int8_gemms(_compile_mxu(specs, pp, enc, x), specs)
     # what keeps seg_pallas off: Mosaic refuses the fused body
     with pytest.raises(Exception):
         build_pallas_segment(specs, pp, enc, interpret=False).lower(x).compile()
+
+
+def _compile_mxu(specs, pp, enc, x):
+    """``build_mxu_segment``'s executable compiled for `x`'s device,
+    its bound weights described there too."""
+    fn = build_mxu_segment(specs, pp, enc)
+    weights = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=x.sharding),
+        fn.args,
+    )
+    return fn.func.lower(*weights, x).compile()
+
+
+def _assert_int8_gemms(compiled, specs):
+    """Every conv / fc of the span is a convolution over int8 ±1
+    weights; nothing counts bits."""
+    text = compiled.as_text()
+    n_gemms = sum(s.kind in ("conv", "fc") for s in specs)
+    assert len(re.findall(r" convolution\(", text)) == n_gemms
+    assert "popcnt" not in text
+    for s in specs:
+        if s.kind == "conv":
+            assert f"s8[3,3,{s.in_shape[-1]},{s.units}]" in text
+        elif s.kind == "fc":
+            assert f"s8[{s.in_shape[0]},{s.units}]" in text
+
+
+@pytest.mark.parametrize("name", ("fashion_mnist", "cifar10"))
+def test_whole_model_mxu_segment_compiles_at_b256(
+    one_chip, no_compile_cache, models, name
+):
+    """The node the saturate cells serve, 0:n at B = 256, from packed
+    images: ``seg_mxu`` lowers with int8 GEMMs, including the first
+    conv's 1- or 3-channel input."""
+    m, packed = models[name]
+    specs = tuple(m.specs)
+    x = jax.ShapeDtypeStruct(
+        (256,) + encoded_shape(specs[0].in_shape, "packed"),
+        jnp.int32, sharding=one_chip,
+    )
+    _assert_int8_gemms(_compile_mxu(specs, list(packed), "packed", x), specs)
