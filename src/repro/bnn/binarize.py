@@ -82,6 +82,14 @@ def unpack_bits(words: jax.Array, n: int) -> jax.Array:
     return jnp.where(flat == 1, 1.0, -1.0).astype(jnp.float32)
 
 
+def unpack_pm1(words: jax.Array, n: int) -> jax.Array:
+    """Packed words ``(..., Kw)`` -> int8 ±1 ``(..., n)``, tail lanes
+    dropped."""
+    bits = (words[..., None] >> jnp.arange(PACK_W, dtype=jnp.int32)) & 1
+    bits = bits.reshape(words.shape[:-1] + (-1,))[..., :n]
+    return (2 * bits - 1).astype(jnp.int8)
+
+
 def popcount(x: jax.Array) -> jax.Array:
     """Population count on int32 words, result int32 (two's-complement
     bits, so the sign bit counts like any other lane)."""

@@ -31,10 +31,14 @@ from repro.bnn.binarize import (
     binarize_ste,
     pack_bits,
     popcount,
+    unpack_pm1,
 )
 
 BN_EPS = 1e-5
 BN_MOMENTUM = 0.1
+
+PACKED = "packed"        # int32 bitplane words, 32 activations/word
+UNPACKED = "unpacked"    # one int32 value per element
 
 
 # ---------------------------------------------------------------------------
@@ -47,12 +51,14 @@ class LayerSpec:
     """Static description of one layer in paper notation."""
 
     idx: int            # 1-based position, as in the paper's tables
-    kind: str           # 'conv' | 'mp' | 'step' | 'flat' | 'fc'
+    kind: str           # conv mp step flat fc | stem maxp res gap fcq
     notation: str       # e.g. 'C64', 'MP16', 'S', 'FLAT', 'FC1024'
     in_shape: tuple     # per-example logical shape (no batch), unpacked
     out_shape: tuple    # per-example logical shape (no batch), unpacked
-    # conv/fc: number of output units; step: channel count
+    # conv/fc/stem/res/fcq: number of output units; step/mp/maxp/gap:
+    # channel count
     units: int = 0
+    stride: int = 1     # stem, maxp: 2; res: 1 or 2
 
     @property
     def reduce_dim(self) -> int:
@@ -64,6 +70,45 @@ class LayerSpec:
         return 0
 
 
+# The token grammar, one (pattern, kind) per layer kind: the paper's
+# chain (HEP-BNN Tables I, II), then Bi-Real Net's (arXiv:1808.00278)
+_TOKENS = (
+    (re.compile(r"C(\d+)"), "conv"),
+    (re.compile(r"MP(\d+)"), "mp"),
+    (re.compile(r"S"), "step"),
+    (re.compile(r"FLAT"), "flat"),
+    (re.compile(r"FC(\d+)"), "fc"),
+    (re.compile(r"STEM(\d+)"), "stem"),
+    (re.compile(r"MAXP"), "maxp"),
+    (re.compile(r"RBD?(\d+)"), "res"),
+    (re.compile(r"GAP"), "gap"),
+    (re.compile(r"FCQ(\d+)"), "fcq"),
+)
+
+# (in_encoding, out_encoding) of each kind; mp is absent because it
+# preserves whatever encoding flows in.  Bi-Real Net's residual stream
+# is int32 between its layers: UNPACKED.
+KIND_ENCODINGS = {
+    "conv": (PACKED, UNPACKED),
+    "fc": (PACKED, UNPACKED),
+    "step": (UNPACKED, PACKED),
+    "flat": (PACKED, PACKED),
+    "stem": (PACKED, UNPACKED),
+    "maxp": (UNPACKED, UNPACKED),
+    "res": (UNPACKED, UNPACKED),
+    "gap": (UNPACKED, UNPACKED),
+    "fcq": (UNPACKED, UNPACKED),
+}
+
+
+def match_token(token: str) -> tuple:
+    """``(kind, n)`` of a notation token, ``n`` its number or None."""
+    for pattern, kind in _TOKENS:
+        if m := pattern.fullmatch(token):
+            return kind, int(m.group(1)) if m.groups() else None
+    raise ValueError(f"unknown layer token {token!r}")
+
+
 def parse_notation(
     notation: Sequence[str],
     input_hw: tuple,
@@ -73,48 +118,63 @@ def parse_notation(
     """Build LayerSpecs from paper notation.
 
     The final FC layer maps its input to ``n_classes`` (the paper's
-    trailing '-> 10'); every other FCx maps to x units. Convs are 3x3,
-    SAME (pad value -1); maxpool is 2x2/2 with MPx asserting output x.
+    trailing '-> 10', its x names its input width); every other FCx maps
+    to x units. Convs are 3x3, SAME (pad value -1); maxpool is 2x2/2
+    with MPx asserting output x.
+
+    Bi-Real Net's tokens: ``STEM<c>`` a 7x7/2 int8 conv of the ±1 image
+    (zero padding 3) to c channels, requantized to int32; ``MAXP`` a
+    3x3/2 max pool (padding 1) of int32 values; ``RB<c>`` a residual
+    block, ``RBD<c>`` the same at stride 2 with a downsampling shortcut
+    (to c from c/2 channels at the published widths); ``GAP`` a global
+    sum pool; ``FCQ<n>`` an int8 fc with bias to n scores.
     """
     specs: list[LayerSpec] = []
-    h, w = input_hw
-    shape: tuple = (h, w, in_channels)
-    last_fc = max(
-        i for i, s in enumerate(notation) if s.startswith("FC")
-    )
+    shape: tuple = (*input_hw, in_channels)
+    fcs = [i for i, t in enumerate(notation) if match_token(t)[0] == "fc"]
     for i, token in enumerate(notation):
         idx = i + 1
-        if m := re.fullmatch(r"C(\d+)", token):
-            cout = int(m.group(1))
-            out = (shape[0], shape[1], cout)
-            specs.append(LayerSpec(idx, "conv", token, shape, out, cout))
-        elif m := re.fullmatch(r"MP(\d+)", token):
-            tgt = int(m.group(1))
+        kind, n = match_token(token)
+        units, stride = shape[-1], 1
+        if kind == "conv":
+            out, units = (shape[0], shape[1], n), n
+        elif kind == "mp":
             out = (shape[0] // 2, shape[1] // 2, shape[2])
-            if out[0] != tgt:
+            if out[0] != n:
                 raise ValueError(
                     f"{token} at layer {idx}: 2x2 pool of {shape} gives "
-                    f"{out[0]}, expected {tgt}"
+                    f"{out[0]}, expected {n}"
                 )
-            specs.append(LayerSpec(idx, "mp", token, shape, out, shape[2]))
-        elif token == "S":
-            specs.append(
-                LayerSpec(idx, "step", token, shape, shape, shape[-1])
-            )
-        elif token == "FLAT":
-            out = (int(np.prod(shape)),)
-            specs.append(LayerSpec(idx, "flat", token, shape, out))
-        elif m := re.fullmatch(r"FC(\d+)", token):
-            din = int(np.prod(shape))
-            dout = n_classes if i == last_fc else int(m.group(1))
-            if i == last_fc and int(m.group(1)) != din:
-                # paper notation: trailing FCx names its input width
-                pass
-            out = (dout,)
-            specs.append(LayerSpec(idx, "fc", token, (din,), out, dout))
-        else:
-            raise ValueError(f"unknown layer token {token!r}")
-        shape = specs[-1].out_shape
+        elif kind == "step":
+            out = shape
+        elif kind == "flat":
+            out, units = (int(np.prod(shape)),), 0
+        elif kind == "fc":
+            shape = (int(np.prod(shape)),)
+            out = (n_classes if i == fcs[-1] else n,)
+            units = out[0]
+        elif kind in ("stem", "maxp"):
+            half = ((shape[0] - 1) // 2 + 1, (shape[1] - 1) // 2 + 1)
+            units = n if kind == "stem" else shape[2]
+            out, stride = (*half, units), 2
+        elif kind == "res":
+            stride = 2 if token.startswith("RBD") else 1
+            if (stride == 1 and shape[2] != n) or (
+                    stride == 2 and (shape[0] % 2 or shape[1] % 2)):
+                raise ValueError(
+                    f"{token} at layer {idx}: an identity shortcut keeps "
+                    f"{n} channels, a stride-2 one halves an even map; "
+                    f"the input is {shape}"
+                )
+            out, units = (shape[0] // stride, shape[1] // stride, n), n
+        elif kind == "gap":
+            out = (shape[2],)
+        else:  # fcq
+            if len(shape) != 1:
+                raise ValueError(f"{token} at layer {idx} takes a vector")
+            out, units = (n,), n
+        specs.append(LayerSpec(idx, kind, token, shape, out, units, stride))
+        shape = out
     return specs
 
 
@@ -128,6 +188,11 @@ def init_bnn_params(key: jax.Array, specs: Sequence[LayerSpec]) -> list[dict]:
     'gamma'/'beta'. State: step 'mean'/'var' (running stats)."""
     params: list[dict] = []
     for spec in specs:
+        if spec.kind not in ("conv", "mp", "step", "flat", "fc"):
+            raise ValueError(
+                f"layer {spec.notation!r} has no fp-sim (training) path; "
+                "pack its integer parameters with pack_params"
+            )
         if spec.kind == "conv":
             cin = spec.in_shape[-1]
             key, sub = jax.random.split(key)
@@ -266,13 +331,17 @@ def binarize_input(x01: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def extract_patch_words(x_words: jax.Array) -> jax.Array:
-    """(B,H,W,Cw) packed -> (B,H,W,9*Cw) 3x3 SAME patches. Spatial pad
-    words are 0 == all -1 pixels (the binary-domain pad value)."""
+def extract_patch_words(x_words: jax.Array, stride: int = 1) -> jax.Array:
+    """(B,H,W,Cw) packed -> (B,Ho,Wo,9*Cw) 3x3 patches at `stride`,
+    padding 1, ``Ho = (H - 1) // stride + 1`` (SAME at stride 1).
+    Spatial pad words are 0 == all -1 pixels (the binary-domain pad
+    value)."""
     b, h, w, cw = x_words.shape
+    ho, wo = (h - 1) // stride + 1, (w - 1) // stride + 1
     xp = jnp.pad(x_words, ((0, 0), (1, 1), (1, 1), (0, 0)))
     offs = [
-        xp[:, dy : dy + h, dx : dx + w, :]
+        xp[:, dy : dy + stride * (ho - 1) + 1 : stride,
+           dx : dx + stride * (wo - 1) + 1 : stride, :]
         for dy in range(3)
         for dx in range(3)
     ]
@@ -321,3 +390,157 @@ def fc_packed(
     xn = ~(x_words[:, None, :] ^ w_words[None, :, :])
     agree = jnp.sum(popcount(xn), axis=-1, dtype=jnp.int32)
     return 2 * agree - k_true
+
+
+# ---------------------------------------------------------------------------
+# Bi-Real Net (arXiv:1808.00278) layers, packed inference
+# ---------------------------------------------------------------------------
+#
+# Values between these layers are the int32 residual stream.  Every batch
+# norm (with a binary conv's per-channel scale) is folded into fixed
+# point: a per-channel int32 multiplier m, bias b and one right shift k,
+# ``floor((m * s + b) / 2**k)``.  The int8 convs (stem, shortcut) and the
+# fc take int8 weights and accumulate in int32.
+
+
+def xnor_gemm(a: jax.Array, w_words: jax.Array, k_true: int) -> jax.Array:
+    """a (B,P,Kw), w (N,Kw) packed -> (B,P,N) int32 exact ±1 dots: the
+    packed fc over every window."""
+    b, p, kw = a.shape
+    return fc_packed(a.reshape(b * p, kw), w_words, k_true).reshape(b, p, -1)
+
+
+def requant(s: jax.Array, m, b, k) -> jax.Array:
+    """``floor((m * s + b) / 2**k)`` per channel (the last axis), in
+    int32: an arithmetic right shift is the floor division."""
+    return (m * s + b) >> k
+
+
+def quantize_int8(s: jax.Array, m, b, k) -> jax.Array:
+    """:func:`requant` clipped to [-128, 127], as int8."""
+    return jnp.clip(requant(s, m, b, k), -128, 127).astype(jnp.int8)
+
+
+def border_classes(n_in: int, stride: int) -> np.ndarray:
+    """For each output row (or column) of a 3x3 window at `stride`,
+    padding 1, over `n_in` input rows: which outer taps lie on the
+    padding, bit 0 the first (dy = 0), bit 1 the last (dy = 2)."""
+    i = stride * np.arange((n_in - 1) // stride + 1)
+    return (i - 1 < 0).astype(np.int32) + 2 * (i + 1 > n_in - 1)
+
+
+_PADDED_TAPS = (set(), {0}, {2}, {0, 2})    # by border class
+
+
+def border_table(w_pm1: np.ndarray) -> np.ndarray:
+    """(4, 4, Cout) int32 from ±1 weights (3, 3, Cin, Cout): for each
+    (row class, column class) the sum of the weights at the taps that
+    lie on the padding.  The packed path reads those taps as -1 (pad
+    words 0); adding the sum gives zero padding's result.  Nine of the
+    sixteen classes occur where both sides of the map exceed one
+    pixel."""
+    tap = np.asarray(w_pm1, np.int64).sum(axis=2)          # (3, 3, Cout)
+    out = np.zeros((4, 4, tap.shape[-1]), np.int32)
+    for r, rows in enumerate(_PADDED_TAPS):
+        for c, cols in enumerate(_PADDED_TAPS):
+            on_pad = np.array([[dy in rows or dx in cols for dx in range(3)]
+                               for dy in range(3)])
+            out[r, c] = tap[on_pad].sum(axis=0)
+    return out
+
+
+def binary_conv_packed(x: jax.Array, p: dict, stride: int,
+                       gemm=xnor_gemm) -> jax.Array:
+    """A block's binary conv: sign(x) packed, 3x3 at `stride`, zero
+    padding (the -1 pad words, corrected per border class), through
+    `gemm`.  x int32 (B,H,W,Cin) -> int32 (B,Ho,Wo,Cout)."""
+    words = pack_bits(x >= 0)
+    b, h, w, _ = words.shape
+    patches = extract_patch_words(words, stride)
+    _, ho, wo, kw = patches.shape
+    dot = gemm(patches.reshape(b, ho * wo, kw), p["w_words"], p["k_true"])
+    corr = p["corr"][border_classes(h, stride)][:, border_classes(w, stride)]
+    return dot.reshape(b, ho, wo, -1) + corr
+
+
+def residual(x: jax.Array, s: jax.Array, p: dict, spec: LayerSpec):
+    """A block's output from its input stream `x` and its binary conv's
+    sums `s`: the requantized sums plus the shortcut.  The shortcut is
+    `x` itself, or at stride 2 a 2x2 sum pool, requantized to int8, a
+    1x1 int8 conv and its own requantization."""
+    out = requant(s, p["m"], p["b"], p["k"])
+    if spec.stride == 1:
+        return out + x
+    with jax.named_scope(f"{scope_of(spec)}.down"):
+        b, h, w, c = x.shape
+        a = x.reshape(b, h // 2, 2, w // 2, 2, c).sum(axis=(2, 4))
+        q = quantize_int8(a, p["qm"], p["qb"], p["qk"])
+        t = jnp.dot(q, p["sw"], preferred_element_type=jnp.int32)
+        return out + requant(t, p["sm"], p["sb"], p["sk"])
+
+
+def stem_packed(x_words: jax.Array, p: dict, cin: int) -> jax.Array:
+    """7x7/2 int8 conv of the ±1 image, zero padding 3, requantized."""
+    x = unpack_pm1(x_words, cin)
+    s = jax.lax.conv_general_dilated(
+        x, p["w"], (2, 2), ((3, 3), (3, 3)),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=jnp.int32,
+    )
+    return requant(s, p["m"], p["b"], p["k"])
+
+
+def maxpool3_packed(x: jax.Array) -> jax.Array:
+    """3x3/2 max pool, padding 1 (padded taps never win), on int32."""
+    return jax.lax.reduce_window(
+        x, jnp.int32(np.iinfo(np.int32).min), jax.lax.max,
+        (1, 3, 3, 1), (1, 2, 2, 1), ((0, 0), (1, 1), (1, 1), (0, 0)),
+    )
+
+
+def fcq_packed(x: jax.Array, p: dict) -> jax.Array:
+    """int32 (B, Din) requantized to int8, an int8 fc, plus the int32
+    bias: the int32 scores."""
+    q = quantize_int8(x, p["qm"], p["qb"], p["qk"])
+    return jnp.dot(q, p["w"], preferred_element_type=jnp.int32) + p["bias"]
+
+
+def scope_of(spec: LayerSpec) -> str | None:
+    """The ``jax.named_scope`` a Bi-Real layer runs under, so that the
+    device trace names its operations: ``stem`` (with its pool),
+    ``res<idx>`` (its shortcut ``res<idx>.down``), ``head`` (the pool
+    and the fc)."""
+    if spec.kind == "res":
+        return f"res{spec.idx}"
+    return {"stem": "stem", "maxp": "stem", "gap": "head",
+            "fcq": "head"}.get(spec.kind)
+
+
+def apply_packed(spec: LayerSpec, p: dict, x: jax.Array,
+                 gemm=xnor_gemm) -> jax.Array:
+    """One layer of packed inference, the CPU implementation.  `gemm`,
+    ``(a (B,P,Kw), w (N,Kw), k_true) -> (B,P,N)``, computes a residual
+    block's binary GEMM (a kernel variant's builder)."""
+    if spec.kind == "conv":
+        return conv_packed(x, p["w_words"], p["k_true"])
+    if spec.kind == "mp":
+        return maxpool_packed(x)
+    if spec.kind == "step":
+        return step_packed(x, p["thresh"], p["flip"])
+    if spec.kind == "flat":
+        return flat_packed(x, spec.in_shape[-1])
+    if spec.kind == "fc":
+        return fc_packed(x, p["w_words"], p["k_true"])
+    with jax.named_scope(scope_of(spec)):
+        if spec.kind == "stem":
+            return stem_packed(x, p, spec.in_shape[-1])
+        if spec.kind == "maxp":
+            return maxpool3_packed(x)
+        if spec.kind == "res":
+            return residual(x, binary_conv_packed(x, p, spec.stride, gemm),
+                            p, spec)
+        if spec.kind == "gap":
+            return jnp.sum(x, axis=(1, 2), dtype=jnp.int32)
+        if spec.kind == "fcq":
+            return fcq_packed(x, p)
+    raise ValueError(f"unknown layer kind {spec.kind!r}")

@@ -9,6 +9,7 @@ HEP mapper.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Sequence
 
 import jax
@@ -27,6 +28,14 @@ FASHION_MNIST_NOTATION = (
 CIFAR10_NOTATION = (
     "C64", "S", "C64", "MP16", "S", "C256", "S", "C256", "MP8", "S",
     "C512", "S", "C512", "MP4", "S", "FLAT", "FC1024", "S", "FC1024",
+)
+# Bi-Real-Net-18 (arXiv:1808.00278; birealnet18 = BiRealNet(BasicBlock,
+# [4, 4, 4, 4])): stem, 16 residual blocks of one binary conv each,
+# stages of 64/128/256/512 channels, the head to 1000 classes
+BIREALNET18_NOTATION = (
+    "STEM64", "MAXP", *("RB64",) * 4,
+    *(tok for c in (128, 256, 512) for tok in (f"RBD{c}", *(f"RB{c}",) * 3)),
+    "GAP", "FCQ1000",
 )
 
 
@@ -50,22 +59,27 @@ class BNNModel:
 _REGISTRY = {
     "fashion_mnist": (FASHION_MNIST_NOTATION, (28, 28), 1, 10),
     "cifar10": (CIFAR10_NOTATION, (32, 32), 3, 10),
+    "birealnet18": (BIREALNET18_NOTATION, (224, 224), 3, 1000),
 }
 
 
-def build_model(name: str, *, scale: float = 1.0) -> BNNModel:
-    """Build a paper model. ``scale`` < 1 shrinks channel/unit counts
-    (for smoke tests) while preserving the layer structure."""
+def build_model(
+    name: str, *, scale: float = 1.0, input_hw: tuple | None = None
+) -> BNNModel:
+    """Build a registered model. ``scale`` < 1 shrinks channel/unit
+    counts (for smoke tests) while preserving the layer structure;
+    ``input_hw`` replaces the registered input size (Bi-Real-Net's
+    layers take any size whose stages halve evenly)."""
     notation, hw, cin, ncls = _REGISTRY[name]
     if scale != 1.0:
         def shrink(tok: str) -> str:
-            import re
-            if m := re.fullmatch(r"(C|FC)(\d+)", tok):
+            if m := re.fullmatch(r"(C|FC|STEM|RBD?)(\d+)", tok):
                 n = max(32, int(int(m.group(2)) * scale))
                 n = (n // 32) * 32  # keep word-aligned
                 return f"{m.group(1)}{n}"
             return tok
         notation = tuple(shrink(t) for t in notation)
+    hw = tuple(input_hw) if input_hw is not None else hw
     specs = tuple(L.parse_notation(notation, hw, cin, ncls))
     return BNNModel(name, specs, hw, cin, ncls)
 
@@ -75,25 +89,44 @@ def build_model(name: str, *, scale: float = 1.0) -> BNNModel:
 # ---------------------------------------------------------------------------
 
 
+def _conv_words(w: np.ndarray) -> np.ndarray:
+    """(3,3,Cin,Cout) -> words (Cout, 9*ceil(Cin/32)), tail bit 1, in
+    the patch order of extract_patch_words (dy-major, dx-minor)."""
+    cin, cout = w.shape[2], w.shape[3]
+    wt = np.transpose(w, (3, 0, 1, 2)).reshape(cout, 9, cin)
+    return np_pack_bits(np.sign(wt) + 0.5, pad_bit=1).reshape(cout, -1)
+
+
+def _ints(v, dtype=np.int32) -> jax.Array:
+    return jnp.asarray(np.rint(np.asarray(v)).astype(dtype))
+
+
+def _fold(p: dict, pre: str = "") -> dict:
+    """A fixed-point fold's m, b (int32 per channel) and k."""
+    return {pre + key: _ints(p[pre + key]) for key in ("m", "b", "k")}
+
+
 def pack_params(specs: Sequence[L.LayerSpec], params: list[dict]) -> list[dict]:
     """Quantize trained fp params into packed inference params.
 
     conv:  w (3,3,Cin,Cout) -> words (Cout, 9*ceil(Cin/32)), tail bit 1
     fc:    w (Din,Dout)     -> words (Dout, ceil(Din/32)),   tail bit 1
     step:  gamma/beta/mean/var -> (thresh int32, flip bool) per channel
+
+    Bi-Real layers take integer-valued arrays: fixed-point folds
+    ``m``/``b``/``k`` (``q*`` quantizing an int8 input, ``s*`` a
+    shortcut's), int8 weights ``w`` (stem HWIO, fc (Din, Dout)) and
+    ``sw`` (a shortcut's (Cin, Cout)), the fc's int32 ``bias``, and a
+    block's ±1 ``w`` (3,3,Cin,Cout), packed like a conv's with its
+    border table (:func:`repro.bnn.layers.border_table`).
     """
     packed: list[dict] = []
     for spec, p in zip(specs, params):
         if spec.kind == "conv":
             w = np.asarray(p["w"])              # (3,3,Cin,Cout)
-            cin, cout = w.shape[2], w.shape[3]
-            # (Cout, 9, Cin): patch order must match extract_patch_words
-            # (dy-major, dx-minor), i.e. w[dy,dx] for dy in 0..2, dx in 0..2
-            wt = np.transpose(w, (3, 0, 1, 2)).reshape(cout, 9, cin)
-            words = np_pack_bits(np.sign(wt) + 0.5, pad_bit=1)
             packed.append(
-                {"w_words": jnp.asarray(words.reshape(cout, -1)),
-                 "k_true": 9 * cin}
+                {"w_words": jnp.asarray(_conv_words(w)),
+                 "k_true": 9 * w.shape[2]}
             )
         elif spec.kind == "fc":
             w = np.asarray(p["w"])              # (Din, Dout)
@@ -104,6 +137,21 @@ def pack_params(specs: Sequence[L.LayerSpec], params: list[dict]) -> list[dict]:
         elif spec.kind == "step":
             t, f = fold_bn(p["gamma"], p["beta"], p["mean"], p["var"])
             packed.append({"thresh": jnp.asarray(t), "flip": jnp.asarray(f)})
+        elif spec.kind == "stem":
+            packed.append({"w": _ints(p["w"], np.int8), **_fold(p)})
+        elif spec.kind == "res":
+            w = np.asarray(p["w"])              # (3,3,Cin,Cout) ±1
+            q = {"w_words": jnp.asarray(_conv_words(w)),
+                 "k_true": 9 * w.shape[2],
+                 "corr": jnp.asarray(L.border_table(np.where(w >= 0, 1, -1))),
+                 **_fold(p)}
+            if spec.stride != 1:
+                q.update(_fold(p, "q"), sw=_ints(p["sw"], np.int8),
+                         **_fold(p, "s"))
+            packed.append(q)
+        elif spec.kind == "fcq":
+            packed.append({"w": _ints(p["w"], np.int8),
+                           "bias": _ints(p["bias"]), **_fold(p, "q")})
         else:
             packed.append({})
     return packed
@@ -122,14 +170,5 @@ def forward_packed(
     Returns int32 class scores."""
     x = x_words
     for spec, p in zip(specs, packed):
-        if spec.kind == "conv":
-            x = L.conv_packed(x, p["w_words"], p["k_true"])
-        elif spec.kind == "mp":
-            x = L.maxpool_packed(x)
-        elif spec.kind == "step":
-            x = L.step_packed(x, p["thresh"], p["flip"])
-        elif spec.kind == "flat":
-            x = L.flat_packed(x, spec.in_shape[-1])
-        elif spec.kind == "fc":
-            x = L.fc_packed(x, p["w_words"], p["k_true"])
+        x = L.apply_packed(spec, p, x)
     return x

@@ -24,6 +24,7 @@ from repro.core.parallel_config import CONFIGS, CPU, aspects_of
 
 # --- TPU v5e hardware constants (per chip) --------------------------------
 PEAK_BF16_FLOPS = 197e12          # MXU
+PEAK_INT8_OPS = 393e12            # MXU, int8 x int8 -> int32
 HBM_BW = 819e9                    # bytes/s
 ICI_BW_PER_LINK = 50e9            # bytes/s
 VPU_INT_OPS = 4e12                # int32 vector ops/s (VPU, est.)
@@ -66,8 +67,11 @@ class GemmDims:
 
 
 def gemm_dims_for(spec: LayerSpec, batch: int) -> GemmDims | None:
-    if spec.kind == "conv":
-        h, w, cin = spec.in_shape
+    """The packed xnor GEMM of a conv, fc or residual block (its binary
+    conv, one window per output position); None for other kinds."""
+    if spec.kind in ("conv", "res"):
+        h, w, _ = spec.out_shape
+        cin = spec.in_shape[-1]
         return GemmDims(
             b=batch, p=h * w, n=spec.units, kw=9 * math.ceil(cin / 32)
         )
@@ -235,6 +239,37 @@ def elementwise_time_tpu(spec: LayerSpec, config: str, batch: int) -> float:
     )
 
 
+def int8_macs(spec: LayerSpec) -> int:
+    """int8 x int8 MACs of one example: the stem, a downsampling
+    block's 1x1 shortcut, the int8 fc; 0 for other kinds."""
+    out = spec.out_shape
+    cin = spec.in_shape[-1]
+    if spec.kind == "stem":
+        return out[0] * out[1] * 49 * cin * out[2]
+    if spec.kind == "res" and spec.stride != 1:
+        return out[0] * out[1] * cin * out[2]
+    if spec.kind == "fcq":
+        return cin * out[0]
+    return 0
+
+
+def int32_stream_time_tpu(
+    spec: LayerSpec, config: str, batch: int, registry=None
+) -> float:
+    """Seconds of a Bi-Real layer's work beside any packed GEMM: its
+    int8 products on the MXU and its int32 input and output crossing
+    HBM (or, host-placed, the host's memory and integer units)."""
+    import numpy as np
+
+    ops = 2 * batch * int8_macs(spec)
+    bytes_ = batch * 4 * (
+        int(np.prod(spec.in_shape)) + int(np.prod(spec.out_shape))
+    )
+    if _is_host(config, registry):
+        return max(bytes_ / CPU_BW, ops / CPU_INT_OPS)
+    return max(bytes_ / HBM_BW, ops / PEAK_INT8_OPS)
+
+
 def layer_time_split_tpu(
     spec: LayerSpec, config: str, batch: int, registry=None
 ) -> tuple:
@@ -246,6 +281,16 @@ def layer_time_split_tpu(
     CPU placement reports zero transfer.
     """
     dims = gemm_dims_for(spec, batch)
+    if spec.kind in ("stem", "res", "fcq"):
+        kern = int32_stream_time_tpu(spec, config, batch, registry)
+        if dims is not None:
+            kern += gemm_kernel_time_tpu(dims, config, registry)
+        elif not _is_host(config, registry):
+            kern += DISPATCH_OVERHEAD
+        return _split(
+            kern, elementwise_transfer_times_tpu(spec, batch), config,
+            registry,
+        )
     if dims is None:
         return _split(
             elementwise_kernel_time_tpu(spec, config, batch, registry),
@@ -331,6 +376,8 @@ def xla_segment_kernel_time_tpu(specs, batch: int, registry=None) -> float:
     separate traffic term), GEMM activations still cross HBM."""
     total = 0.0
     for spec in specs:
+        if spec.kind in ("stem", "res", "fcq"):
+            total += int32_stream_time_tpu(spec, "xla_fused", batch, registry)
         dims = gemm_dims_for(spec, batch)
         if dims is None:
             continue                    # fused into the producer GEMM

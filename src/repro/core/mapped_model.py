@@ -53,6 +53,11 @@ def _layer_fn(spec, packed, config: str, registry=None) -> Callable:
     `registry` overrides the default resolver (matching a custom
     registry passed to ``autotune_bnn_model``)."""
     reg = registry if registry is not None else DEFAULT_REGISTRY
+    if spec.kind == "res":
+        gemm = reg.get(config).builder
+        return lambda x: L.apply_packed(spec, packed, x, gemm)
+    if spec.kind in ("stem", "maxp", "gap", "fcq"):
+        return lambda x: L.apply_packed(spec, packed, x)
     if spec.kind == "conv":
         w, k_true = packed["w_words"], packed["k_true"]
         builder = reg.get(config).builder
@@ -105,6 +110,29 @@ def node_name(k: int, node) -> str:
     ``jit_<name>``, and the ``node`` of its spans."""
     return (f"node{k}_{node.start}_{node.stop}_"
             f"{node.fused_variant or node.placement}")
+
+
+def node_stream(specs) -> tuple:
+    """``(blocks, bytes)``: the residual blocks among `specs` and the
+    int32 stream bytes they read and write for one example."""
+    blocks = [s for s in specs if s.kind == "res"]
+    per_example = sum(
+        4 * (int(np.prod(s.in_shape)) + int(np.prod(s.out_shape)))
+        for s in blocks
+    )
+    return len(blocks), per_example
+
+
+def dispatch_span(name: str, batch: int, stream: tuple):
+    """The ``pipeline.dispatch`` span of one node call.  A node with
+    residual blocks (`stream`, from :func:`node_stream`) also carries
+    their count, ``res_blocks``, and the stream bytes they read and
+    write at `batch`, ``stream_bytes``."""
+    blocks, per_example = stream
+    if not blocks:
+        return span("pipeline.dispatch", node=name, batch=batch)
+    return span("pipeline.dispatch", node=name, batch=batch,
+                res_blocks=blocks, stream_bytes=per_example * batch)
 
 
 def build_node_fns(
@@ -181,24 +209,27 @@ def _jit_named(fn, name: str) -> Callable:
     return functools.partial(jitted, operands) if operands else jitted
 
 
-def run_plan(node_fns, *, device=None) -> Callable:
+def run_plan(node_fns, *, device=None, specs=()) -> Callable:
     """The plan interpreter: ``fn(x_words) -> np.ndarray`` walking the
     nodes with the transfer/sync structure the plan encodes — H2D
     (``jax.device_put``) before a ``transfer_in`` node, a blocking
     sync after every node (the per-node cost structure the profiler
     measured), D2H (``np.asarray``) after a ``transfer_out`` node.
-    Between co-placed nodes the activation stays where it is."""
+    Between co-placed nodes the activation stays where it is.  `specs`,
+    the model's layers, tell each node's dispatch span what it counts."""
     dev = device if device is not None else jax.devices()[0]
     names = [node_name(k, node) for k, (node, _) in enumerate(node_fns)]
+    streams = [node_stream(specs[node.start:node.stop])
+               for node, _ in node_fns]
 
     def run(x_words):
         x = np.asarray(x_words)          # input starts on the host
         batch = x.shape[0]
-        for name, (node, fn) in zip(names, node_fns):
+        for name, stream, (node, fn) in zip(names, streams, node_fns):
             if node.transfer_in and not isinstance(x, jax.Array):
                 with span("pipeline.h2d", batch=batch):
                     x = jax.device_put(x, dev)
-            with span("pipeline.dispatch", node=name, batch=batch):
+            with dispatch_span(name, batch, stream):
                 out = fn(x)
             jax.block_until_ready(out)
             x = to_host(out, name, batch) if node.transfer_out else out
@@ -252,7 +283,7 @@ def build_mapped_model(
         config, mode="layers" if elide_transfers else "roundtrip"
     )
     node_fns = build_node_fns(model, packed_params, config, plan, registry)
-    return run_plan(node_fns)
+    return run_plan(node_fns, specs=model.specs)
 
 
 def build_segment_fns(

@@ -65,14 +65,17 @@ from __future__ import annotations
 import dataclasses
 import json
 
+from repro.bnn.layers import (  # noqa: F401 -- PACKED, UNPACKED re-exported
+    KIND_ENCODINGS,
+    PACKED,
+    UNPACKED,
+    match_token,
+)
 from repro.core.mapper import (
     DEVICE,
     HOST,
     EfficientConfiguration,
 )
-
-PACKED = "packed"        # int32 bitplane words, 32 activations/word
-UNPACKED = "unpacked"    # one int32 pre-activation per element
 
 MODES = ("segments", "layers", "roundtrip", "whole")
 
@@ -83,27 +86,13 @@ class PlanError(ValueError):
 
 
 def kind_of_label(label: str) -> str:
-    """Layer kind from a profile label (``"L3:MP14" -> "mp"``).  The
-    token after ``Li:`` is the paper's notation; prefix-matched with
-    the longer tokens first so ``FLAT``/``FC`` never read as conv."""
-    token = label.split(":", 1)[-1]
-    for prefix, kind in (
-        ("MP", "mp"), ("FLAT", "flat"), ("FC", "fc"),
-        ("C", "conv"), ("S", "step"),
-    ):
-        if token.startswith(prefix):
-            return kind
-    raise PlanError(f"unrecognized layer label {label!r}")
-
-
-# (in_encoding, out_encoding) demanded/produced by each kind; mp is
-# absent because it preserves whatever encoding flows in
-_KIND_ENCODINGS = {
-    "conv": (PACKED, UNPACKED),
-    "fc": (PACKED, UNPACKED),
-    "step": (UNPACKED, PACKED),
-    "flat": (PACKED, PACKED),
-}
+    """Layer kind from a profile label (``"L3:MP14" -> "mp"``): the
+    token after ``Li:`` in the notation's grammar
+    (``repro.bnn.layers.match_token``)."""
+    try:
+        return match_token(label.split(":", 1)[-1])[0]
+    except ValueError:
+        raise PlanError(f"unrecognized layer label {label!r}") from None
 
 
 def layer_encodings(kinds) -> tuple:
@@ -119,9 +108,9 @@ def layer_encodings(kinds) -> tuple:
         if kind == "mp":
             out.append((cur, cur))
             continue
-        if kind not in _KIND_ENCODINGS:
+        if kind not in KIND_ENCODINGS:
             raise PlanError(f"unknown layer kind {kind!r} at layer {i}")
-        need, prod = _KIND_ENCODINGS[kind]
+        need, prod = KIND_ENCODINGS[kind]
         if cur != need:
             raise PlanError(
                 f"encoding mismatch at layer {i} ({kind}): requires "

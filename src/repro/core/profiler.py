@@ -46,6 +46,7 @@ cannot run.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import time
 from typing import Callable, Sequence
@@ -275,16 +276,18 @@ def prune_survivors(
 
 
 def gemm_shape_of(spec: L.LayerSpec, packed: dict, batch: int):
-    """The GEMM dispatch shape of a conv/fc layer at `batch` (None for
-    elementwise layers) — what variant applicability predicates see."""
-    if spec.kind not in ("conv", "fc"):
+    """The packed GEMM dispatch shape of a conv/fc layer, or of a
+    residual block's binary conv (one window per output position), at
+    `batch` (None for layers without one) — what variant applicability
+    predicates see."""
+    if spec.kind not in ("conv", "fc", "res"):
         return None
     w_words = packed["w_words"]
     n, kw = int(w_words.shape[0]), int(w_words.shape[1])
-    if spec.kind == "conv":
-        h, w, _ = spec.in_shape
-        return GemmShape(b=batch, p=h * w, n=n, kw=kw)
-    return GemmShape(b=batch, p=1, n=n, kw=kw)
+    if spec.kind == "fc":
+        return GemmShape(b=batch, p=1, n=n, kw=kw)
+    h, w, _ = spec.out_shape
+    return GemmShape(b=batch, p=h * w, n=n, kw=kw)
 
 
 def _layer_impls(
@@ -321,7 +324,28 @@ def _layer_impls(
 
         return {cfg: gemm_for(cfg) for cfg in candidates}
 
-    if spec.kind == "mp":
+    if spec.kind == "res":
+        # the block around its binary GEMM, under each variant.  Its
+        # arrays are operands, not constants, so blocks of one shape are
+        # one program: the persistent cache compiles each variant once
+        # per shape, not once per block
+        k_true = packed["k_true"]
+        arrays = {k: v for k, v in packed.items() if k != "k_true"}
+
+        def block_for(cfg):
+            gemm = registry.get(cfg).builder
+
+            @jax.jit
+            def f(arrays, x):
+                return L.apply_packed(spec, {**arrays, "k_true": k_true}, x,
+                                      gemm)
+
+            return functools.partial(f, arrays)
+
+        return {cfg: block_for(cfg) for cfg in candidates}
+    if spec.kind in ("stem", "maxp", "gap", "fcq"):
+        f = jax.jit(lambda x: L.apply_packed(spec, packed, x))
+    elif spec.kind == "mp":
         f = jax.jit(L.maxpool_packed)
     elif spec.kind == "step":
         t, fl = packed["thresh"], packed["flip"]
@@ -342,16 +366,7 @@ def _capture_layer_inputs(
     x = x_words
     for spec, p in zip(model.specs, packed_params):
         xs.append(x)
-        if spec.kind == "conv":
-            x = L.conv_packed(x, p["w_words"], p["k_true"])
-        elif spec.kind == "mp":
-            x = L.maxpool_packed(x)
-        elif spec.kind == "step":
-            x = L.step_packed(x, p["thresh"], p["flip"])
-        elif spec.kind == "flat":
-            x = L.flat_packed(x, spec.in_shape[-1])
-        elif spec.kind == "fc":
-            x = L.fc_packed(x, p["w_words"], p["k_true"])
+        x = L.apply_packed(spec, p, x)
     return xs
 
 

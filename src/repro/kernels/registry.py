@@ -114,23 +114,27 @@ class SegmentShape:
     """Shape of one fused-segment dispatch — what segment-scope
     applicability predicates see.  ``b`` batch, ``n_layers`` layers in
     the span, ``work`` total word-level GEMM MACs, ``vmem_bytes``
-    resident footprint (weights + peak intermediate)."""
+    resident footprint (weights + peak intermediate), ``kinds`` the
+    layer kinds in the span."""
 
     b: int
     n_layers: int
     work: int
     vmem_bytes: int
+    kinds: frozenset = frozenset()
 
 
 def segment_shape_of(specs, packed_params, batch: int) -> SegmentShape:
     """The :class:`SegmentShape` of a layer slice at `batch`."""
+    specs = tuple(specs)
     return SegmentShape(
         b=batch,
-        n_layers=len(tuple(specs)),
+        n_layers=len(specs),
         work=segment_gemm_work(specs, packed_params, batch),
         vmem_bytes=segment_vmem_bytes(
             specs, packed_params, infer_in_encoding(specs)
         ),
+        kinds=frozenset(s.kind for s in specs),
     )
 
 
@@ -309,7 +313,13 @@ def _seg_pallas_builder(specs, packed_params, in_encoding=None):
     )
 
 
+# the kinds whose parameters the fused kernel body takes as refs
+_SEG_PALLAS_KINDS = frozenset({"conv", "mp", "step", "flat", "fc"})
+
+
 def _seg_pallas_applicable(shape: SegmentShape, platform: str) -> bool:
+    if not shape.kinds <= _SEG_PALLAS_KINDS:
+        return False
     if platform == "tpu":
         # Mosaic refuses the fused body's one-example blocks (a (1, N)
         # output block is neither 8-row aligned nor the whole batch);

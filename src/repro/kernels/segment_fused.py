@@ -42,6 +42,14 @@ products of ±1 values is an integer that int32 holds, and the
 binary-domain pad (−1) and dropped tail lanes match the packed
 convention (``repro.bnn.binarize``).
 
+Bi-Real Net's kinds (stem, 3x3 max pool, residual block, global pool,
+int8 fc) run in ``seg_xla`` and ``seg_mxu`` through the same layer
+functions (``repro.bnn.layers.apply_packed``), with the int32 residual
+stream between them; only a block's binary conv differs: xnor-popcount
+on -1-padded patch words plus the border correction in ``seg_xla``, an
+int8 ±1 convolution with zero padding in ``seg_mxu``.  ``seg_pallas``
+takes the paper's kinds only.
+
 Builder signature (segment scope): ``builder(specs, packed_params,
 in_encoding=None) -> fn(x) -> out`` over the segment's layer slice.
 ``in_encoding`` ("packed" / "unpacked") disambiguates a segment that
@@ -61,15 +69,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.bnn import layers as L
-from repro.bnn.binarize import PACK_W, pack_bits
-
-PACKED = "packed"
-UNPACKED = "unpacked"
-
-# layer kinds whose input encoding is implied by the kind itself
-_IN_ENCODING = {
-    "conv": PACKED, "fc": PACKED, "flat": PACKED, "step": UNPACKED,
-}
+from repro.bnn.binarize import PACK_W, pack_bits, unpack_pm1
+from repro.bnn.layers import KIND_ENCODINGS, PACKED, UNPACKED
 
 
 def infer_in_encoding(specs: Sequence[L.LayerSpec]) -> str:
@@ -78,8 +79,8 @@ def infer_in_encoding(specs: Sequence[L.LayerSpec]) -> str:
     unpacked — pooling packed words would OR bitplanes, which no valid
     chain produces mid-network without an adjacent non-mp layer."""
     for spec in specs:
-        if spec.kind in _IN_ENCODING:
-            return _IN_ENCODING[spec.kind]
+        if spec.kind in KIND_ENCODINGS:
+            return KIND_ENCODINGS[spec.kind][0]
     return UNPACKED
 
 
@@ -97,12 +98,7 @@ def segment_out_encoding(
 ) -> str:
     enc = in_encoding
     for spec in specs:
-        if spec.kind in ("conv", "fc"):
-            enc = UNPACKED
-        elif spec.kind == "step":
-            enc = PACKED
-        elif spec.kind == "flat":
-            enc = PACKED
+        enc = KIND_ENCODINGS.get(spec.kind, (enc, enc))[1]
     return enc
 
 
@@ -110,18 +106,7 @@ def _run_chain(specs: Sequence[L.LayerSpec], packed_params, x):
     """The segment's reference layer chain on a batched array —
     the single source of semantics for both fused builders."""
     for spec, p in zip(specs, packed_params):
-        if spec.kind == "conv":
-            x = L.conv_packed(x, p["w_words"], p["k_true"])
-        elif spec.kind == "mp":
-            x = L.maxpool_packed(x)
-        elif spec.kind == "step":
-            x = L.step_packed(x, p["thresh"], p["flip"])
-        elif spec.kind == "flat":
-            x = L.flat_packed(x, spec.in_shape[-1])
-        elif spec.kind == "fc":
-            x = L.fc_packed(x, p["w_words"], p["k_true"])
-        else:
-            raise ValueError(spec.kind)
+        x = L.apply_packed(spec, p, x)
     return x
 
 
@@ -151,10 +136,7 @@ def segment_vmem_bytes(
         in_elems = 1
         for d in encoded_shape(spec.in_shape, enc):
             in_elems *= d
-        if spec.kind in ("conv", "fc"):
-            enc = UNPACKED
-        elif spec.kind == "step":
-            enc = PACKED
+        enc = KIND_ENCODINGS.get(spec.kind, (enc, enc))[1]
         out_elems = 1
         for d in encoded_shape(spec.out_shape, enc):
             out_elems *= d
@@ -165,15 +147,16 @@ def segment_vmem_bytes(
 def segment_gemm_work(
     specs: Sequence[L.LayerSpec], packed_params, batch: int
 ) -> int:
-    """Total word-level MAC count of the segment's GEMM layers at
-    `batch` — the interpret-mode size proxy (``GemmShape.work``
-    summed)."""
+    """Total word-level MAC count of the segment's packed GEMMs (conv,
+    fc, a residual block's binary conv) at `batch` — the interpret-mode
+    size proxy (``GemmShape.work`` summed)."""
     work = 0
     for spec, p in zip(specs, packed_params):
-        if spec.kind not in ("conv", "fc"):
+        if "w_words" not in p:
             continue
         n, kw = (int(d) for d in p["w_words"].shape)
-        pwin = spec.in_shape[0] * spec.in_shape[1] if spec.kind == "conv" else 1
+        out = spec.out_shape
+        pwin = out[0] * out[1] if len(out) == 3 else 1
         work += batch * pwin * n * kw
     return work
 
@@ -205,31 +188,30 @@ def build_xla_segment(
 # ---------------------------------------------------------------------------
 
 
-def _unpack_pm1(words: jax.Array, n: int) -> jax.Array:
-    """Packed words ``(..., Kw)`` -> int8 ±1 ``(..., n)``, tail lanes
-    dropped."""
-    bits = (words[..., None] >> jnp.arange(PACK_W, dtype=jnp.int32)) & 1
-    bits = bits.reshape(words.shape[:-1] + (-1,))[..., :n]
-    return (2 * bits - 1).astype(jnp.int8)
-
-
 def _mxu_params(specs: Sequence[L.LayerSpec], packed_params) -> list:
     """Each conv's ``w_words (Cout, 9*Cw)`` as HWIO ``(3, 3, Cin,
     Cout)`` int8 ±1, each fc's ``(Dout, Kw)`` as ``(Din, Dout)``: the
     patch order of ``extract_patch_words`` (dy-major, dx-minor), tail
-    lanes dropped."""
+    lanes dropped.  A residual block's binary weights likewise, beside
+    its other parameters (no border table: the MXU pads 0 itself); the
+    int8 layers' parameters as they are."""
     out = []
     for spec, p in zip(specs, packed_params):
-        if spec.kind == "conv":
+        if spec.kind in ("conv", "res"):
             cin = spec.in_shape[-1]
             w = jnp.asarray(p["w_words"])
-            w = _unpack_pm1(w.reshape(w.shape[0], 9, -1), cin)
-            out.append({"w": w.reshape(-1, 3, 3, cin).transpose(1, 2, 3, 0)})
+            w = unpack_pm1(w.reshape(w.shape[0], 9, -1), cin)
+            w = w.reshape(-1, 3, 3, cin).transpose(1, 2, 3, 0)
+            rest = {k: v for k, v in p.items()
+                    if k not in ("w_words", "k_true", "corr")}
+            out.append({"w": w, **rest})
         elif spec.kind == "fc":
-            w = _unpack_pm1(jnp.asarray(p["w_words"]), spec.in_shape[0])
+            w = unpack_pm1(jnp.asarray(p["w_words"]), spec.in_shape[0])
             out.append({"w": w.T})
         elif spec.kind == "step":
             out.append({"thresh": p["thresh"], "flip": p["flip"]})
+        elif spec.kind in ("stem", "fcq"):
+            out.append(dict(p))
         else:
             out.append({})
     return out
@@ -242,9 +224,20 @@ def _run_mxu_chain(specs, params, x, form: str):
     """The segment chain on int8 ±1 activations.  `form` is what `x`
     holds: packed words, unpacked int32 sums, or ±1 lanes."""
     for spec, p in zip(specs, params):
-        if spec.kind in ("conv", "fc"):
+        if spec.kind == "res":
+            with jax.named_scope(L.scope_of(spec)):
+                s = jax.lax.conv_general_dilated(
+                    jnp.where(x >= 0, jnp.int8(1), jnp.int8(-1)), p["w"],
+                    (spec.stride, spec.stride), ((1, 1), (1, 1)),
+                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                    preferred_element_type=jnp.int32,
+                )
+                x = L.residual(x, s, p, spec)
+        elif spec.kind in ("stem", "maxp", "gap", "fcq"):
+            x, form = L.apply_packed(spec, p, x), UNPACKED
+        elif spec.kind in ("conv", "fc"):
             if form == PACKED:
-                x = _unpack_pm1(x, spec.in_shape[-1])
+                x = unpack_pm1(x, spec.in_shape[-1])
             if spec.kind == "conv":
                 x = jnp.pad(
                     x, ((0, 0), (1, 1), (1, 1), (0, 0)), constant_values=-1

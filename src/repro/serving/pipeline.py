@@ -53,7 +53,13 @@ import jax
 import numpy as np
 
 from repro.bnn.models import BNNModel
-from repro.core.mapped_model import build_node_fns, node_name, to_host
+from repro.core.mapped_model import (
+    build_node_fns,
+    dispatch_span,
+    node_name,
+    node_stream,
+    to_host,
+)
 from repro.core.mapper import EfficientConfiguration
 from repro.core.parallel_config import CPU, FULL_GPU
 from repro.core.plan import SegmentPlan, build_plan
@@ -111,6 +117,10 @@ class SegmentPipeline:
         self.node_names = [
             node_name(k, node) for k, (node, _) in enumerate(self.segment_fns)
         ]
+        self.node_streams = [
+            node_stream(model.specs[node.start:node.stop])
+            for node, _ in self.segment_fns
+        ]
         self.device = device if device is not None else jax.devices()[0]
 
     @property
@@ -128,7 +138,7 @@ class SegmentPipeline:
             if seg.on_device:
                 with span("pipeline.h2d", batch=batch):
                     x = jax.device_put(x, self.device)
-            with span("pipeline.dispatch", node=name, batch=batch):
+            with dispatch_span(name, batch, self.node_streams[s]):
                 out = fn(x)
             jax.block_until_ready(out)
             # D2H after a device segment, before the next one
@@ -198,8 +208,8 @@ class SegmentPipeline:
                         with span("pipeline.h2d", batch=batch):
                             x = jax.device_put(x, self.device)
                     t0 = time.perf_counter() if observer is not None else 0.0
-                    with span("pipeline.dispatch", node=names[s],
-                              batch=batch):
+                    with dispatch_span(names[s], batch,
+                                       self.node_streams[s]):
                         out = fn(x)
                     if observer is not None:
                         jax.block_until_ready(out)
@@ -219,8 +229,8 @@ class SegmentPipeline:
                     t0 = time.perf_counter() if observer is not None else 0.0
                     if s > 0:
                         x = to_host(x, names[s - 1], batch)
-                    with span("pipeline.dispatch", node=names[s],
-                              batch=batch):
+                    with dispatch_span(names[s], batch,
+                                       self.node_streams[s]):
                         out = fn(x)
                     if observer is not None:
                         jax.block_until_ready(out)

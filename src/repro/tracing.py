@@ -18,7 +18,10 @@ requests:
 ``engine.step``        ``ServingEngine.step``: drain, run, completion
 ``batcher.form``       one micro-batch stacked and padded
 ``pipeline.h2d``       an input staged on the device
-``pipeline.dispatch``  one plan node's call (``node``: its stable name)
+``pipeline.dispatch``  one plan node's call (``node``: its stable name;
+                       a node with residual blocks adds ``res_blocks``
+                       and ``stream_bytes``, the int32 stream they read
+                       and write at the node's batch)
 ``pipeline.d2h``       ``np.asarray`` of a node's device result
 ``engine.complete``    one micro-batch's requests completed
 ``python.gc``          a garbage-collector pause (``generation``)

@@ -8,8 +8,10 @@ here is deterministic given its seed/arguments — no wall clock, no
 real profiling.
 """
 
+import importlib.util
 import math
 import random
+from pathlib import Path
 from types import SimpleNamespace
 
 from repro.bnn.layers import LayerSpec
@@ -278,3 +280,37 @@ def loglinear_table(model, batches=(1, 4), weights=TRUTH_WEIGHTS):
         kernel_times=kernels, h2d_times=h2d, d2h_times=d2h,
         provenance="analytic",
     )
+
+
+BIREAL_REFERENCE = (Path(__file__).resolve().parents[1] / "onchip" / "configs"
+                    / "birealnet_reference.py")
+
+
+def bireal_reference():
+    """The benchmark's plain Bi-Real-Net reference module, which imports
+    nothing of the program."""
+    spec = importlib.util.spec_from_file_location("birealnet_reference",
+                                                  BIREAL_REFERENCE)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def birealnet(scale=1.0, input_hw=None, seed=0):
+    """``(model, ref, cfg, params, packed)``: Bi-Real-Net-18 built by the
+    program, the plain reference, its configuration dict, the weights
+    and integer parameters the reference draws from `seed`, and the
+    program's packing of them."""
+    import jax
+
+    from repro.bnn import build_model
+    from repro.bnn.models import pack_params
+
+    model = build_model("birealnet18", scale=scale, input_hw=input_hw)
+    ref = bireal_reference()
+    cfg = {"layers": [s.notation for s in model.specs],
+           "input_hw": list(model.input_hw),
+           "in_channels": model.in_channels, "n_classes": model.n_classes}
+    params = jax.device_get(
+        jax.jit(lambda k: ref.init_params(cfg, k))(jax.random.PRNGKey(seed)))
+    return model, ref, cfg, params, pack_params(model.specs, params)

@@ -39,6 +39,8 @@ from repro.kernels.segment_fused import (
 )
 from repro.kernels.xnor_popcount import xnor_gemm_pallas
 
+from tests.fixtures import birealnet
+
 BATCH = 16
 PALLAS_LAYER_VARIANTS = tuple(
     v.name for v in DEFAULT_REGISTRY
@@ -52,6 +54,11 @@ LAYERS = (
     ("cifar10", "C256", 1),
     ("cifar10", "C512", 0),
     ("cifar10", "C512", 1),
+)
+# Bi-Real-Net-18's block GEMMs, one of each (windows, neurons) shape
+BIREAL_LAYERS = tuple(
+    ("birealnet18", notation, 0)
+    for notation in ("RB64", "RBD128", "RBD256", "RB512")
 )
 
 
@@ -93,6 +100,8 @@ def models():
     for name in ("fashion_mnist", "cifar10"):
         m = build_model(name)
         out[name] = (m, pack_params(m.specs, m.init(jax.random.PRNGKey(0))))
+    m, *_, packed = birealnet()
+    out["birealnet18"] = (m, packed)
     return out
 
 
@@ -106,7 +115,8 @@ def _layer_shape(models, name, notation, occurrence):
 
 
 @pytest.mark.parametrize("variant", PALLAS_LAYER_VARIANTS)
-@pytest.mark.parametrize("layer", LAYERS, ids=lambda t: f"{t[0]}-{t[1]}.{t[2]}")
+@pytest.mark.parametrize("layer", LAYERS + BIREAL_LAYERS,
+                         ids=lambda t: f"{t[0]}-{t[1]}.{t[2]}")
 def test_pallas_layer_variant_compiles_iff_applicable(
     one_chip, no_compile_cache, models, layer, variant
 ):
@@ -207,3 +217,20 @@ def test_whole_model_mxu_segment_compiles_at_b256(
         jnp.int32, sharding=one_chip,
     )
     _assert_int8_gemms(_compile_mxu(specs, list(packed), "packed", x), specs)
+
+
+def test_bireal_blocks_compile_on_the_mxu(one_chip, no_compile_cache, models):
+    """Bi-Real-Net-18's last stage-1 block and the stride-2 block after
+    it at b64, as ``seg_mxu`` runs them: a zero-padded int8 conv each
+    (the second strided), the downsampling shortcut's int8 1x1 conv,
+    no popcount; ``seg_xla`` compiles too."""
+    m, packed = models["birealnet18"]
+    specs, pp = tuple(m.specs[5:7]), list(packed[5:7])
+    assert [s.notation for s in specs] == ["RB64", "RBD128"]
+    x = jax.ShapeDtypeStruct((64, *specs[0].in_shape), jnp.int32,
+                             sharding=one_chip)
+    text = _compile_mxu(specs, pp, "unpacked", x).as_text()
+    assert len(re.findall(r" convolution\(", text)) == 3
+    assert "s8[3,3,64,64]" in text and "s8[3,3,64,128]" in text
+    assert "popcnt" not in text
+    build_xla_segment(specs, pp, "unpacked").lower(x).compile()

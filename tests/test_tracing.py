@@ -210,3 +210,71 @@ def test_an_empty_step_still_runs_nothing(small, tmp_path):
         assert eng.step(force=True) == 0
     names = Counter(e[0] for e in _program_events(tmp_path))
     assert names["engine.step"] == 1 and names["pipeline.dispatch"] == 0
+
+
+@pytest.fixture(scope="module")
+def bireal():
+    from tests.fixtures import birealnet
+
+    m, ref, cfg, params, packed = birealnet(scale=0.25, input_hw=(32, 32))
+    rng = np.random.default_rng(7)
+    xw = np.asarray(prepare_input_packed(
+        rng.random((2, 32, 32, 3), dtype=np.float32)))
+    return m, packed, xw
+
+
+BIREAL_SCOPES = ["stem", "res3", "res7", "res7.down", "res18", "head"]
+
+
+def test_bireal_layers_run_under_their_named_scopes_in_every_path(bireal):
+    """Every path that runs a Bi-Real layer names its operations by the
+    layer's scope: the packed forward, both fused variants, and a plan's
+    per-layer nodes."""
+    from repro.kernels.segment_fused import (
+        build_mxu_segment, build_xla_segment,
+    )
+
+    m, packed, xw = bireal
+    mxu = build_mxu_segment(m.specs, packed)
+    texts = {
+        "forward": jax.jit(
+            lambda x: forward_packed(m.specs, packed, x)).lower(xw),
+        "seg_xla": build_xla_segment(m.specs, packed).lower(xw),
+        "seg_mxu": mxu.func.lower(*mxu.args, xw),
+    }
+    table, ec = _mixed(m, packed, batch=2, mapping=(FULL_GPU,) * 20)
+    x = xw
+    layer_texts = []
+    for node, fn in build_node_fns(m, packed, ec,
+                                   build_plan(ec, mode="layers")):
+        layer_texts.append(fn.lower(x).as_text(debug_info=True))
+        x = fn(x)
+    texts = {k: v.as_text(debug_info=True) for k, v in texts.items()}
+    texts["layers"] = "\n".join(layer_texts)
+    for path, text in texts.items():
+        for scope in BIREAL_SCOPES:
+            assert f"/{scope}/" in text, (path, scope)
+
+
+def test_a_dispatch_counts_its_residual_blocks_and_stream_bytes(
+        bireal, tmp_path):
+    m, packed, xw = bireal
+    mapping = (CPU,) * 4 + (FULL_GPU,) * 16
+    table, ec = _mixed(m, packed, batch=2, mapping=mapping)
+    pipe = SegmentPipeline(m, packed, ec)
+    with jax.profiler.trace(str(tmp_path)):
+        pipe.run_serial(xw)
+    spans = [e[3] for e in _program_events(tmp_path)
+             if e[0] == "pipeline.dispatch"]
+    assert len(spans) == len(pipe.node_names) == 2
+    host, device = spans
+    # layers 2, 3 (blocks 1, 2) on the host; 14 blocks on the device
+    assert host["res_blocks"] == 2 and device["res_blocks"] == 14
+
+    def stream(specs):
+        return sum(4 * (np.prod(s.in_shape) + np.prod(s.out_shape))
+                   for s in specs if s.kind == "res")
+
+    assert host["stream_bytes"] == 2 * stream(m.specs[:4])
+    assert device["stream_bytes"] == 2 * stream(m.specs[4:])
+    assert all(s["batch"] == 2 for s in spans)
