@@ -43,6 +43,7 @@ from repro.bnn.models import BNNModel
 from repro.core.mapper import EfficientConfiguration
 from repro.core.plan import SegmentPlan, build_plan
 from repro.kernels.registry import DEFAULT_REGISTRY, SCOPE_SEGMENT
+from repro.kernels.segment_fused import fuses_stem
 from repro.tracing import span
 
 
@@ -112,27 +113,33 @@ def node_name(k: int, node) -> str:
             f"{node.fused_variant or node.placement}")
 
 
-def node_stream(specs) -> tuple:
-    """``(blocks, bytes)``: the residual blocks among `specs` and the
-    int32 stream bytes they read and write for one example."""
+def node_stream(specs, variant: str | None = None) -> tuple:
+    """``(blocks, bytes, stem_fused)``: the residual blocks among
+    `specs`, the int32 stream bytes they read and write for one example,
+    and whether the node's fused `variant` runs a stem and its max pool
+    as one kernel (``seg_mxu``, :func:`fuses_stem`)."""
     blocks = [s for s in specs if s.kind == "res"]
     per_example = sum(
         4 * (int(np.prod(s.in_shape)) + int(np.prod(s.out_shape)))
         for s in blocks
     )
-    return len(blocks), per_example
+    return (len(blocks), per_example,
+            variant == "seg_mxu" and fuses_stem(specs))
 
 
 def dispatch_span(name: str, batch: int, stream: tuple):
     """The ``pipeline.dispatch`` span of one node call.  A node with
     residual blocks (`stream`, from :func:`node_stream`) also carries
     their count, ``res_blocks``, and the stream bytes they read and
-    write at `batch`, ``stream_bytes``."""
-    blocks, per_example = stream
-    if not blocks:
-        return span("pipeline.dispatch", node=name, batch=batch)
-    return span("pipeline.dispatch", node=name, batch=batch,
-                res_blocks=blocks, stream_bytes=per_example * batch)
+    write at `batch`, ``stream_bytes``; a node whose stem and max pool
+    run as one kernel carries ``stem_fused=1``."""
+    blocks, per_example, stem_fused = stream
+    args = {}
+    if blocks:
+        args.update(res_blocks=blocks, stream_bytes=per_example * batch)
+    if stem_fused:
+        args["stem_fused"] = 1
+    return span("pipeline.dispatch", node=name, batch=batch, **args)
 
 
 def build_node_fns(
@@ -219,7 +226,7 @@ def run_plan(node_fns, *, device=None, specs=()) -> Callable:
     the model's layers, tell each node's dispatch span what it counts."""
     dev = device if device is not None else jax.devices()[0]
     names = [node_name(k, node) for k, (node, _) in enumerate(node_fns)]
-    streams = [node_stream(specs[node.start:node.stop])
+    streams = [node_stream(specs[node.start:node.stop], node.fused_variant)
                for node, _ in node_fns]
 
     def run(x_words):

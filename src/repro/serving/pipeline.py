@@ -118,7 +118,8 @@ class SegmentPipeline:
             node_name(k, node) for k, (node, _) in enumerate(self.segment_fns)
         ]
         self.node_streams = [
-            node_stream(model.specs[node.start:node.stop])
+            node_stream(model.specs[node.start:node.stop],
+                        node.fused_variant)
             for node, _ in self.segment_fns
         ]
         self.device = device if device is not None else jax.devices()[0]

@@ -21,7 +21,9 @@ requests:
 ``pipeline.dispatch``  one plan node's call (``node``: its stable name;
                        a node with residual blocks adds ``res_blocks``
                        and ``stream_bytes``, the int32 stream they read
-                       and write at the node's batch)
+                       and write at the node's batch; a ``seg_mxu``
+                       node whose stem and max pool run as one kernel
+                       adds ``stem_fused=1``)
 ``pipeline.d2h``       ``np.asarray`` of a node's device result
 ``engine.complete``    one micro-batch's requests completed
 ``python.gc``          a garbage-collector pause (``generation``)

@@ -24,7 +24,14 @@ from repro.bnn.models import (
 )
 from repro.core.parallel_config import FULL_GPU
 from repro.core.plan import PlanError, kind_of_label, layer_encodings
-from repro.kernels.segment_fused import build_mxu_segment, build_xla_segment
+from repro.kernels import segment_fused
+from repro.kernels.segment_fused import (
+    build_mxu_segment,
+    build_xla_segment,
+    fuses_stem,
+    stem_pool_mxu,
+    stem_pool_params,
+)
 
 from tests.fixtures import bireal_reference, birealnet
 
@@ -191,3 +198,95 @@ def test_the_paper_models_keep_their_layers():
                                              "fc"}
     with pytest.raises(ValueError, match="fp-sim"):
         build_model("birealnet18").init(jax.random.PRNGKey(0))
+
+
+# ---------------------------------------------------------------------------
+# seg_mxu's stem: the 7x7/2 conv, its requantization and the 3x3/2 max pool
+# as one kernel (interpret mode here)
+# ---------------------------------------------------------------------------
+
+
+def _stem(h, w, c, seed):
+    """A stem and its pool over an (h, w) RGB map to c channels: the
+    layers, and the reference's weights and fold packed for them."""
+    ref = bireal_reference()
+    cfg = {"layers": [f"STEM{c}", "MAXP"], "input_hw": [h, w],
+           "in_channels": 3, "n_classes": 10}
+    params = jax.device_get(jax.jit(lambda k: ref.init_params(cfg, k))(
+        jax.random.PRNGKey(seed)))
+    specs = L.parse_notation(cfg["layers"], (h, w), 3, 10)
+    return ref, specs, params
+
+
+def _stem_check(spec, p, batch, seed):
+    x = jax.random.randint(jax.random.PRNGKey(seed),
+                           (batch, *spec.in_shape[:2], 1), 0, 8, jnp.int32)
+    want = L.maxpool3_packed(L.stem_packed(x, p, 3))
+    got = stem_pool_mxu(x, stem_pool_params(spec, p), spec)
+    assert got.dtype == jnp.int32 and got.shape == want.shape
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+# the published map at b2; small and odd maps, whose pooled rows and
+# columns take every pad border (odd conv sides pad the pool's last tap)
+STEM_MAPS = [(224, 224, 2), (8, 8, 2), (14, 14, 2), (30, 30, 2), (9, 13, 3)]
+
+
+@pytest.mark.parametrize("h, w, batch", STEM_MAPS,
+                         ids=lambda v: str(v))
+def test_the_fused_stem_equals_the_stem_and_its_pool(h, w, batch):
+    _, specs, params = _stem(h, w, 64, seed=h * 1000 + w)
+    p = pack_params(specs, params)[0]
+    _stem_check(specs[0], p, batch, seed=h + w)
+
+
+@pytest.mark.parametrize("k_end", [0, 1])
+def test_the_fused_stem_at_the_ends_of_its_folds(k_end):
+    """Channels with m > 0, m < 0 and m = 0, |m|, b and k at the ends of
+    the reference's stem ranges, weights at +-127."""
+    ref, specs, params = _stem(14, 30, 64, seed=31 + k_end)
+    lo, hi, b_max, k_lo, k_hi = ref.STEM
+    c_lo, c_hi = ref.STEM_CENTER
+    params[0]["m"] = np.resize([hi, -hi, 0, lo, -lo], 64).astype(np.float32)
+    params[0]["b"] = np.resize(
+        [-b_max - hi * c_hi, b_max - lo * c_lo, 0, b_max, -b_max],
+        64).astype(np.float32)
+    params[0]["k"] = np.float32((k_lo, k_hi)[k_end])
+    w = np.array(params[0]["w"])
+    w[0, :, 0], w[6, :, 2], w[3, 3] = 127, -127, -127
+    params[0]["w"] = w
+    p = pack_params(specs, params)[0]
+    _stem_check(specs[0], p, 2, seed=5 + k_end)
+
+
+def test_the_fused_stem_takes_symmetric_int8_weights():
+    _, specs, params = _stem(8, 8, 64, seed=3)
+    params[0]["w"] = np.array(params[0]["w"])
+    params[0]["w"][2, 2, 1, 5] = -128
+    with pytest.raises(ValueError, match="-127, 127"):
+        stem_pool_params(specs[0], pack_params(specs, params)[0])
+
+
+@pytest.mark.parametrize("start, stop, fused", [
+    (0, 2, True),      # the stem and its pool
+    (0, 3, True),      # and the first block
+    (0, 1, False),     # a segment that ends at the stem
+    (1, 3, False),     # one that starts at the pool
+])
+def test_seg_mxu_fuses_a_stem_only_with_the_pool_after_it(
+        small, monkeypatch, start, stop, fused):
+    m, packed, xw, _ = small
+    calls = []
+
+    def counted(*args):
+        calls.append(args[2].idx)
+        return stem_pool_mxu(*args)
+
+    monkeypatch.setattr(segment_fused, "stem_pool_mxu", counted)
+    specs, pp = m.specs[start:stop], packed[start:stop]
+    assert fuses_stem(specs) is fused
+    x = forward_packed(m.specs[:start], packed[:start], xw)
+    got = build_mxu_segment(specs, pp)(x)
+    assert np.array_equal(np.asarray(got),
+                          np.asarray(forward_packed(specs, pp, x)))
+    assert calls == ([1] if fused else [])

@@ -9,7 +9,9 @@ paper models' published widths:
   and every one reported not applicable is one Mosaic refuses;
 * ``seg_pallas`` is not applicable on ``"tpu"``; ``seg_xla`` and
   ``seg_mxu``, which serve fused device segments there, compile, the
-  latter with its ±1 products as int8 convolutions and no popcount.
+  latter with its ±1 products as int8 convolutions and no popcount;
+* ``seg_mxu``'s fused stem compiles natively, and a ``seg_mxu`` node
+  holds it exactly where it has a stem and its pool.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and under several
@@ -29,13 +31,17 @@ from jax.sharding import SingleDeviceSharding
 from repro.bnn import build_model
 from repro.bnn.models import pack_params
 from repro.core.profiler import gemm_shape_of
+from repro.kernels import segment_fused
 from repro.kernels.registry import DEFAULT_REGISTRY, segment_shape_of
 from repro.kernels.segment_fused import (
     build_mxu_segment,
     build_pallas_segment,
     build_xla_segment,
     encoded_shape,
+    fuses_stem,
     infer_in_encoding,
+    stem_pool_mxu,
+    stem_pool_params,
 )
 from repro.kernels.xnor_popcount import xnor_gemm_pallas
 
@@ -234,3 +240,51 @@ def test_bireal_blocks_compile_on_the_mxu(one_chip, no_compile_cache, models):
     assert "s8[3,3,64,64]" in text and "s8[3,3,64,128]" in text
     assert "popcnt" not in text
     build_xla_segment(specs, pp, "unpacked").lower(x).compile()
+
+
+@pytest.fixture
+def native_stem(monkeypatch):
+    """The fused stem as Mosaic compiles it: the host here is a CPU, so
+    the kernel would otherwise pick interpret mode."""
+    monkeypatch.setattr(segment_fused, "_interpret", lambda: False)
+
+
+def _described(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def test_fused_stem_compiles_natively_at_b64(
+    one_chip, no_compile_cache, models, native_stem
+):
+    """Bi-Real-Net-18's stem and max pool at b64 as one Mosaic kernel:
+    the (64, 112, 112, 64) conv map is no buffer of the program."""
+    m, packed = models["birealnet18"]
+    spec = m.specs[0]
+    fp = _described(stem_pool_params(spec, packed[0]), one_chip)
+    x = jax.ShapeDtypeStruct((64, 224, 224, 1), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda fp, x: stem_pool_mxu(x, fp, spec)).lower(
+        fp, x).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "s32[64,112,112,64]" not in text
+
+
+@pytest.mark.parametrize("name, batch", [
+    ("fashion_mnist", 256), ("cifar10", 256), ("birealnet18", 64)])
+def test_mxu_node_holds_a_stem_kernel_iff_it_has_a_stem(
+    one_chip, no_compile_cache, models, native_stem, name, batch
+):
+    """The node each saturate cell serves, 0:n from packed images: the
+    paper models' has no stem and no Mosaic kernel; Bi-Real-Net-18's
+    runs its stem as one, and holds no (64, 112, 112, 64) map."""
+    m, packed = models[name]
+    specs = tuple(m.specs)
+    x = jax.ShapeDtypeStruct(
+        (batch,) + encoded_shape(specs[0].in_shape, "packed"),
+        jnp.int32, sharding=one_chip,
+    )
+    text = _compile_mxu(specs, list(packed), "packed", x).as_text()
+    assert fuses_stem(specs) is (name == "birealnet18")
+    assert ("tpu_custom_call" in text) is fuses_stem(specs)
+    assert "s32[64,112,112,64]" not in text

@@ -278,3 +278,22 @@ def test_a_dispatch_counts_its_residual_blocks_and_stream_bytes(
     assert host["stream_bytes"] == 2 * stream(m.specs[:4])
     assert device["stream_bytes"] == 2 * stream(m.specs[4:])
     assert all(s["batch"] == 2 for s in spans)
+
+
+@pytest.mark.parametrize("variant, mark", [("seg_mxu", 1), ("seg_xla", None)])
+def test_a_dispatch_marks_a_fused_stem(bireal, tmp_path, variant, mark):
+    """A seg_mxu node whose stem and max pool run as one kernel carries
+    ``stem_fused=1``; a seg_xla node over the same layers does not."""
+    import dataclasses
+
+    m, packed, xw = bireal
+    n = len(m.specs)
+    table, ec = _mixed(m, packed, batch=2, mapping=(FULL_GPU,) * n)
+    ec = dataclasses.replace(ec, fused_segments=((0, n, variant, 1e-6),))
+    pipe = SegmentPipeline(m, packed, ec)
+    with jax.profiler.trace(str(tmp_path)):
+        pipe.run_serial(xw)
+    (args,) = [e[3] for e in _program_events(tmp_path)
+               if e[0] == "pipeline.dispatch"]
+    assert args.get("stem_fused") == mark
+    assert args["res_blocks"] == 16
